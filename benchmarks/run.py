@@ -27,6 +27,8 @@ def main() -> None:
                     help="snapshot directory (default: results/)")
     args = ap.parse_args()
 
+    from repro.utils import compile_cache
+    compile_cache.enable()
     from benchmarks import (kernels_bench, roofline_report, round_bench,
                             sim_bench, workloads_bench, zo_path_bench)
     suites = [("kernels", kernels_bench.run),

@@ -19,8 +19,9 @@ Rows:
   with a worst-case in-scan telemetry tap (``tap_every=1`` into a
   NullSink, one io_callback per round) vs taps-off (<10% acceptance).
 - ``sim/sharded_dev{n}_us_per_round`` — the clients-axis shard_map round
-  inside the engine on a forced n-device host platform (subprocess), n ∈
-  {1, 2}: the device-scaling story at laptop scale.
+  inside the engine over the process's own n devices (the most that divide
+  M). The CPU sharding check on forced host devices lives in
+  ``tests/test_sim.py``.
 - ``tiered/*`` (``run_tiered``, snapshot ``BENCH_tiered.json``) — the
   host-resident HostStore streaming engine vs the resident scan on the
   same experiment, plus an N=100k-client CPU run with prefetch-stall and
@@ -31,8 +32,6 @@ CPU numbers are regression trackers, not TPU projections (§6).
 from __future__ import annotations
 
 import os
-import subprocess
-import sys
 import time
 
 import jax
@@ -54,52 +53,27 @@ def _quickstart_setup():
     return clients, cfg
 
 
-def _sharded_subprocess_row(n_dev: int):
-    """Time the sharded engine round on a forced n-device host platform.
-    XLA flags must be set before jax init, so this runs out-of-process."""
-    code = f"""
-import time
-import jax
-from repro import sim
-from repro.configs.base import FedZOConfig
-from repro.data.synthetic import make_classification, noniid_shards
-from repro.models.simple import softmax_init, softmax_loss
+def _sharded_row(store, cfg, p0, rounds=10):
+    """Time the sharded engine round over this process's own devices (as
+    many as divide M). It runs in-process: on a TPU the process that has
+    run JAX holds the chips, so a child would find none."""
+    from repro import sim
+    from repro.models.simple import softmax_loss
 
-x, y = make_classification(7000, 784, 10, seed=0)
-clients = noniid_shards(x[:6000], y[:6000], 50)
-cfg = sim.fast_sim_config(FedZOConfig(n_devices=50, n_participating=10,
-                                      local_iters=5, lr=1e-3, mu=1e-3,
-                                      b1=25, b2=20))
-store = sim.build_store(clients)
-mesh = sim.make_clients_mesh()
-rf = sim.make_sharded_round(softmax_loss, cfg, mesh)
-R = 10
-fn = sim.make_experiment_fn(softmax_loss, cfg, R, round_fn=rf, donate=False)
-key = sim.experiment_key(cfg)
-p = softmax_init(None)
-out = fn(p, None, key, None, None, None, store)
-jax.block_until_ready(out[0])
-t0 = time.perf_counter()
-out = fn(p, None, key, None, None, None, store)
-jax.block_until_ready(out[0])
-print("US_PER_ROUND", (time.perf_counter() - t0) / R * 1e6)
-"""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        f" --xla_force_host_platform_device_count={n_dev}")
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "src"),
-         env.get("PYTHONPATH", "")])
-    res = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, timeout=1200, env=env)
-    if res.returncode != 0:
-        raise RuntimeError(f"sharded bench (n_dev={n_dev}) failed:\n"
-                           f"{res.stderr[-2000:]}")
-    for line in res.stdout.splitlines():
-        if line.startswith("US_PER_ROUND"):
-            return float(line.split()[1])
-    raise RuntimeError("sharded bench printed no timing")
+    n_dev = len(jax.devices())
+    while cfg.n_participating % n_dev:
+        n_dev -= 1
+    rf = sim.make_sharded_round(softmax_loss, cfg,
+                                sim.make_clients_mesh(n_dev))
+    fn = sim.make_experiment_fn(softmax_loss, cfg, rounds, round_fn=rf,
+                                donate=False)
+    key = sim.experiment_key(cfg)
+    out = fn(p0, None, key, None, None, None, store)  # compile
+    jax.block_until_ready(out[0])
+    t0 = time.perf_counter()
+    out = fn(p0, None, key, None, None, None, store)
+    jax.block_until_ready(out[0])
+    return n_dev, (time.perf_counter() - t0) / rounds * 1e6
 
 
 def run():
@@ -177,14 +151,9 @@ def run():
     rows.append(("sim/faults_overhead_pct", 0.0,
                  (faults_us / eng_us - 1.0) * 100.0))
 
-    # -- device scaling of the sharded round ----------------------------------
-    dev_counts = [1] + ([2] if (os.cpu_count() or 1) >= 2 else [])
-    for n_dev in dev_counts:
-        try:
-            us = _sharded_subprocess_row(n_dev)
-            rows.append((f"sim/sharded_dev{n_dev}_us_per_round", us, n_dev))
-        except Exception as e:  # noqa: BLE001 — report, don't sink the suite
-            rows.append((f"sim/sharded_dev{n_dev}_ERROR", 0.0, repr(e)[:60]))
+    # -- the sharded round over this process's devices -------------------------
+    n_dev, us = _sharded_row(store, fcfg, p0)
+    rows.append((f"sim/sharded_dev{n_dev}_us_per_round", us, n_dev))
     return rows
 
 

@@ -1,14 +1,19 @@
 """FedZO reproduction package.
 
-Seed replay must be *sharding-invariant*: a direction generated inside a
-GSPMD-partitioned program (e.g. the multi-pod round) must be bit-equal to
-the one a receiver regenerates elsewhere from the same key. Legacy
-non-partitionable threefry does not guarantee that — the partitioner can
-produce different bits when RNG is fused into a sharded program (observed
-as a wrong-direction update in the pod round on jax 0.4.x, where the flag
-still defaults to False). Opt in to partitionable threefry before any key
-is used; newer jax defaults to this behavior.
+Every ZO loss query is a finite difference: the estimator divides
+L(x + μv) − L(x) by μ, with perturbation entries around μ/√d. On a TPU an
+fp32 matmul or convolution by default makes one bfloat16 pass, whose 8-bit
+mantissa rounds such perturbations of the weights away, and the wide
+path's update matvec and the masked aggregation einsums lose precision the
+same way. So the package asks for full fp32 matmul precision before any
+program is traced. On the CPU this changes nothing: fp32 dots there are
+fp32 already.
+
+Seed replay is sharding-invariant because jax's threefry is partitionable
+(the default since jax 0.5): a direction generated inside a
+GSPMD-partitioned program is bit-equal to the one a receiver regenerates
+elsewhere from the same key.
 """
 import jax as _jax
 
-_jax.config.update("jax_threefry_partitionable", True)
+_jax.config.update("jax_default_matmul_precision", "highest")

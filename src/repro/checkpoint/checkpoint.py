@@ -73,7 +73,8 @@ def _restore_arrays(npz_path, like):
     """Load flattened arrays into the structure of ``like`` with loud,
     actionable errors: a missing key or a shape mismatch names the exact
     pytree path and both shapes instead of dying on a bare KeyError /
-    AssertionError."""
+    AssertionError. Only the template's shapes and dtypes are read, so its
+    buffers may already have been donated to an earlier run."""
     loaded = np.load(npz_path)
     flat, treedef = jax.tree_util.tree_flatten_with_path(like)
     leaves = []
@@ -91,7 +92,8 @@ def _restore_arrays(npz_path, like):
                 f"{arr.shape} but the restore target expects "
                 f"{np.shape(ref)}; restoring into a different "
                 f"model/config?")
-        leaves.append(jax.numpy.asarray(arr, dtype=np.asarray(ref).dtype))
+        dtype = ref.dtype if hasattr(ref, "dtype") else np.asarray(ref).dtype
+        leaves.append(jax.numpy.asarray(arr, dtype=dtype))
     return jax.tree_util.tree_unflatten(treedef, leaves)
 
 

@@ -160,7 +160,7 @@ def aircomp_aggregate_flat(deltas, rng, *, snr_db, h_min, d=None, mask=None,
     delta_max = jnp.max(jnp.where(maskf > 0, sq, 0.0))
     noise_var = sigma_w2 * delta_max / (m_div ** 2 * float(d) * P_TX * h_min ** 2)
     noise_std = jnp.sqrt(noise_var)
-    out = kops.zo_walk(mean, jax.random.key_data(rng),
+    out = kops.zo_walk(mean, kops.key_words(rng),
                        jnp.zeros((2,), jnp.int32),
                        jnp.stack([noise_std, jnp.float32(0.0)]),
                        kind="normal", block_rows=block_rows,

@@ -87,13 +87,6 @@ def sample_direction(rng, params, kind: str, dtype=jnp.float32):
     raise ValueError(f"unknown estimator kind {kind!r}")
 
 
-def _key_data(rng):
-    """uint32 [2] key words from either a typed PRNG key or raw key data."""
-    if jnp.issubdtype(jnp.asarray(rng).dtype, jnp.unsignedinteger):
-        return jnp.asarray(rng, jnp.uint32)
-    return jax.random.key_data(rng)
-
-
 def counter_direction(rng, n, params, kind, dtype=jnp.float32):
     """Direction pytree v_n under the flat counter convention.
 
@@ -105,7 +98,7 @@ def counter_direction(rng, n, params, kind, dtype=jnp.float32):
     if ck is None:
         raise ValueError(f"counter convention does not support {kind!r}")
     spec = flat_spec(params)
-    key2 = _key_data(rng)
+    key2 = kops.key_words(rng)
     g = counter_direction_flat(key2, n, spec.d, kind=ck)
     if kind == "sphere":
         g = g * (1.0 / (jnp.linalg.norm(g) + 1e-30))
@@ -216,7 +209,7 @@ def flat_coefficients(loss_fn, buf, spec: FlatSpec, batch, rng, *, mu, b2,
     ck = COUNTER_KINDS.get(kind)
     if ck is None:
         raise ValueError(f"flat path does not support kind={kind!r}")
-    key2 = _key_data(rng)
+    key2 = kops.key_words(rng)
     scale = _scale_factor(spec.d, kind)
     base = (loss_fn(unflatten(buf, spec), batch)
             if base_loss is None else base_loss)
@@ -269,7 +262,7 @@ def flat_apply_coefficients(buf, spec: FlatSpec, rng, coeffs, *, scale=1.0,
     if ck is None:
         raise ValueError(f"flat path does not support kind={kind!r}")
     b2 = coeffs.shape[0]
-    key2 = _key_data(rng)
+    key2 = kops.key_words(rng)
     if inv is None:
         inv = flat_inv_norms(key2, spec, b2, kind, interpret=interpret,
                              block_rows=block_rows)

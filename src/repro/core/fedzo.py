@@ -39,6 +39,7 @@ from repro.configs.base import FedZOConfig
 from repro.core import estimator
 from repro.core.aircomp import (aircomp_aggregate, aircomp_aggregate_flat,
                                 mask_stats, schedule_by_channel)
+from repro.kernels import ops as kops
 from repro.utils.flatparams import (flat_geometry, flat_spec, flatten,
                                     unflatten)
 from repro.utils.tree import tree_add, tree_scale, tree_sub
@@ -79,7 +80,7 @@ def flat_local_iterate(loss_fn, buf, spec, batch, rng, cfg: FedZOConfig,
     zo_dirnorms kernel regenerates all b2 directions, so running it twice
     would double the direction-generation compute of the iterate.
     """
-    key2 = estimator._key_data(rng)
+    key2 = kops.key_words(rng)
     inv = estimator.flat_inv_norms(key2, spec, cfg.b2, cfg.estimator,
                                    block_rows=block_rows)
     coeffs, base = estimator.flat_coefficients(
@@ -484,7 +485,7 @@ def make_pod_round_step(loss_fn_grouped, cfg: FedZOConfig, mesh) -> Callable:
             # regenerates all b2 directions, so running it twice doubles
             # the direction-generation compute of the step)
             inv = estimator.flat_inv_norms(
-                estimator._key_data(rng), spec, cfg.b2, cfg.estimator,
+                kops.key_words(rng), spec, cfg.b2, cfg.estimator,
                 block_rows=br)
             # flat_coefficients handles vector-valued (grouped) losses:
             # coeffs come back [b2, n_pod]

@@ -1,9 +1,11 @@
 """jit'd public wrappers around the Pallas kernels: shape normalization,
 padding to block multiples, pytree-level ZO helpers.
 
-``interpret=None`` auto-selects: compiled on TPU, interpreter elsewhere (this
-container is CPU-only, so tests/benches run the interpreter; the compiled
-path is the production target).
+``interpret=None`` auto-selects from the default backend: on a TPU the
+kernels are compiled by Mosaic (``tpu_custom_call`` in the program); on any
+other backend they run in the Pallas interpreter, which is how the CPU test
+suite runs them. The interpreter is for correctness only: its timings say
+nothing about the chip.
 """
 from __future__ import annotations
 
@@ -20,6 +22,16 @@ def _auto_interpret(interpret):
     if interpret is not None:
         return interpret
     return jax.default_backend() != "tpu"
+
+
+def key_words(rng):
+    """The uint32 [2] key of the counter convention, from a typed PRNG key
+    or raw key data. Threefry keys are exactly these two words; the rbg
+    family's four-word keys give their first two, the only words the
+    kernels ever read."""
+    if not jnp.issubdtype(jnp.asarray(rng).dtype, jnp.unsignedinteger):
+        rng = jax.random.key_data(rng)
+    return jnp.asarray(rng, jnp.uint32)[..., :2]
 
 
 def _pad_to(x, m):

@@ -66,43 +66,45 @@ def zo_replay_ref(x2, key2, coeffs, *, kind="normal"):
     return (x2.astype(jnp.float32) + acc).astype(x2.dtype)
 
 
+def _ascending_sum(n, term, like):
+    """term(0) + term(1) + ... + term(n-1), added in index order."""
+    return jax.lax.fori_loop(0, n, lambda i, t: t + term(i),
+                             jnp.zeros_like(like))
+
+
 def zo_dirnorms_ref(key2, d, b2, n_pad, *, kind="normal", block_rows=None):
-    """Oracle for zo_dirnorms: same per-block partial-sum order."""
+    """Oracle for zo_dirnorms: the same per-block partial sums, added in
+    block order. Vectorized over the blocks, so it runs at full size."""
     from repro.kernels.zo_axpy import BLOCK_ROWS, LANES, counter_gen
     block_rows = block_rows or BLOCK_ROWS
-    per = block_rows * LANES
-    out = []
-    for n in range(b2):
-        total = jnp.float32(0.0)
-        for i in range(n_pad // per):
-            idx = (jnp.uint32(i * per)
-                   + jnp.arange(per, dtype=jnp.uint32))
-            g = counter_gen(kind, key2[0], key2[1], jnp.uint32(n), idx)
-            g = jnp.where(idx < jnp.uint32(d), g, 0.0)
-            total = total + jnp.sum(g * g)
-        out.append(total)
-    return jnp.stack(out)
+    idx = jnp.arange(n_pad, dtype=jnp.uint32).reshape(-1, block_rows, LANES)
+    valid = idx < jnp.asarray(d).astype(jnp.uint32)
+
+    def one(n):
+        g = counter_gen(kind, key2[0], key2[1], n, idx)
+        part = jnp.sum(jnp.where(valid, g * g, 0.0), axis=(1, 2))
+        return _ascending_sum(part.shape[0], lambda i: part[i], part[0])
+
+    return jax.lax.map(one, jnp.arange(b2, dtype=jnp.uint32))
 
 
 def aircomp_reduce_ref(x3, scale, d, *, block_rows=None):
-    """Oracle for aircomp_reduce: same per-block, row-ascending partial-sum
-    order over x3 [M, R, 128]. Returns (mean [R, 128], sq [M])."""
+    """Oracle for aircomp_reduce over x3 [M, R, 128]: the same per-block
+    partial sums added in block order, and the mean accumulated over the
+    rows in ascending order. Returns (mean [R, 128], sq [M])."""
     from repro.kernels.zo_axpy import BLOCK_ROWS, LANES
     block_rows = block_rows or BLOCK_ROWS
     m, r, lanes = x3.shape
-    per = block_rows * lanes
-    sq = [jnp.float32(0.0)] * m
-    mean_blocks = []
-    for i in range(r // block_rows):
-        idx = jnp.uint32(i * per) + jnp.arange(per, dtype=jnp.uint32)
-        valid = (idx < jnp.uint32(d)).reshape(block_rows, lanes)
-        acc = jnp.zeros((block_rows, lanes), jnp.float32)
-        for mi in range(m):
-            x = x3[mi, i * block_rows:(i + 1) * block_rows].astype(jnp.float32)
-            sq[mi] = sq[mi] + jnp.sum(jnp.where(valid, x * x, 0.0))
-            acc = acc + scale[mi] * x
-        mean_blocks.append(acc)
-    return jnp.concatenate(mean_blocks, axis=0), jnp.stack(sq)
+    xb = x3.astype(jnp.float32).reshape(m, -1, block_rows, lanes)
+    idx = jnp.arange(r * lanes, dtype=jnp.uint32).reshape(-1, block_rows,
+                                                          lanes)
+    valid = idx < jnp.asarray(d).astype(jnp.uint32)
+    part = jnp.sum(jnp.where(valid, xb * xb, 0.0), axis=(2, 3))    # [M, nb]
+    sq = _ascending_sum(part.shape[1], lambda i: part[:, i], part[:, 0])
+    mean = _ascending_sum(
+        m, lambda i: scale[i] * x3[i].astype(jnp.float32),
+        x3[0].astype(jnp.float32))
+    return mean, sq
 
 
 def rmsnorm_ref(x, scale, *, eps=1e-6):

@@ -44,6 +44,10 @@ directions from the counter convention.
 
 The 2-D layout [rows, 128] (lane dim last) keeps the kernels inside the
 TPU tiling constraints; ops.py does the flat↔2-D reshape + padding.
+Scalar operands (keys, direction indices, coefficients, the valid length)
+live whole in SMEM, where the TPU compiler allows dynamic scalar reads;
+``zo_dirnorms`` accumulates its [b2] norms in an SMEM output across the
+grid.
 """
 from __future__ import annotations
 
@@ -52,15 +56,25 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCK = 8 * 128 * 64  # 64Ki elements per grid step
 LANES = 128
 BLOCK_ROWS = BLOCK // LANES  # 512 rows of 128 lanes per grid step
+# Scalar operands ride in SMEM as [k, 1] columns: the whole column is one
+# block, and under vmap the batch axis lands in front of the two trailing
+# dims, so the batched block still spans them as the TPU compiler requires.
+SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _col(a, dtype):
+    """Scalar operand(s) as an SMEM [k, 1] column."""
+    return jnp.asarray(a, dtype).reshape(-1, 1)
 
 
 def _axpy2_kernel(ab_ref, x_ref, u_ref, v_ref, o_ref):
-    a = ab_ref[0]
-    b = ab_ref[1]
+    a = ab_ref[0, 0]
+    b = ab_ref[1, 0]
     x = x_ref[...].astype(jnp.float32)
     u = u_ref[...].astype(jnp.float32)
     v = v_ref[...].astype(jnp.float32)
@@ -80,15 +94,15 @@ def zo_axpy2(x, u, v, ab, *, interpret=False, block=BLOCK):
     return pl.pallas_call(
         _axpy2_kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((2,), lambda i: (0,)), spec, spec, spec],
+        in_specs=[SMEM, spec, spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((n,), x.dtype),
         interpret=interpret,
-    )(ab, x, u, v)
+    )(_col(ab, jnp.float32), x, u, v)
 
 
 def _axpy_kernel(a_ref, x_ref, u_ref, o_ref):
-    a = a_ref[0]
+    a = a_ref[0, 0]
     o_ref[...] = (x_ref[...].astype(jnp.float32)
                   + a * u_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
 
@@ -103,11 +117,11 @@ def zo_axpy(x, u, a, *, interpret=False, block=BLOCK):
     return pl.pallas_call(
         _axpy_kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((1,), lambda i: (0,)), spec, spec],
+        in_specs=[SMEM, spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((n,), x.dtype),
         interpret=interpret,
-    )(a, x, u)
+    )(_col(a, jnp.float32), x, u)
 
 
 # ---------------------------------------------------------------------------
@@ -140,11 +154,18 @@ def threefry2x32(k0, k1, c0, c1):
     return x0, x1
 
 
+def _u24_to_f32(b):
+    return (b >> jnp.uint32(8)).astype(jnp.int32).astype(jnp.float32)
+
+
 def _bits_to_normal(b0, b1):
-    """Box-Muller on two uint32 bit planes → one N(0,1) fp32 per element."""
-    u1 = (b0 >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(2.0 ** -24) \
+    """Box-Muller on two uint32 bit planes → one N(0,1) fp32 per element.
+
+    The 24-bit mantissa fields go to float through int32: exact either way
+    (they are < 2^24), and the TPU compiler has no uint32 → float32 cast."""
+    u1 = _u24_to_f32(b0) * jnp.float32(2.0 ** -24) \
         + jnp.float32(2.0 ** -25)                         # (0, 1)
-    u2 = (b1 >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(2.0 ** -24)
+    u2 = _u24_to_f32(b1) * jnp.float32(2.0 ** -24)
     r = jnp.sqrt(jnp.float32(-2.0) * jnp.log(u1))
     return r * jnp.cos(jnp.float32(2.0 * 3.14159265358979323846) * u2)
 
@@ -191,11 +212,12 @@ def _walk_kernel(key_ref, nn_ref, ab_ref, x_ref, o_ref, *, kind):
     i = pl.program_id(0)
     rows, lanes = x_ref.shape
     idx = _block_idx(i, rows, lanes)
-    k0, k1 = key_ref[0], key_ref[1]
-    g_prev = counter_gen(kind, k0, k1, nn_ref[0].astype(jnp.uint32), idx)
-    g_next = counter_gen(kind, k0, k1, nn_ref[1].astype(jnp.uint32), idx)
+    k0, k1 = key_ref[0, 0], key_ref[1, 0]
+    g_prev = counter_gen(kind, k0, k1, nn_ref[0, 0].astype(jnp.uint32), idx)
+    g_next = counter_gen(kind, k0, k1, nn_ref[1, 0].astype(jnp.uint32), idx)
     x = x_ref[...].astype(jnp.float32)
-    o_ref[...] = (x + ab_ref[0] * g_prev + ab_ref[1] * g_next).astype(o_ref.dtype)
+    o_ref[...] = (x + ab_ref[0, 0] * g_prev
+                  + ab_ref[1, 0] * g_next).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("kind", "interpret", "block_rows"))
@@ -211,15 +233,15 @@ def zo_walk(x2, key2, nn, ab, *, kind="normal", interpret=False,
     assert lanes == LANES and r % block_rows == 0, (x2.shape, block_rows)
     grid = (r // block_rows,)
     spec = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
-    small = lambda shape: pl.BlockSpec(shape, lambda i: (0,))
     return pl.pallas_call(
         functools.partial(_walk_kernel, kind=kind),
         grid=grid,
-        in_specs=[small((2,)), small((2,)), small((2,)), spec],
+        in_specs=[SMEM, SMEM, SMEM, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((r, LANES), x2.dtype),
         interpret=interpret,
-    )(key2, nn, ab, x2)
+    )(_col(key2, jnp.uint32), _col(nn, jnp.int32), _col(ab, jnp.float32),
+      x2)
 
 
 # -- zo_replay: single-pass seed-replay update ------------------------------
@@ -229,11 +251,11 @@ def _replay_kernel(key_ref, c_ref, x_ref, o_ref, *, kind, b2):
     i = pl.program_id(0)
     rows, lanes = x_ref.shape
     idx = _block_idx(i, rows, lanes)
-    k0, k1 = key_ref[0], key_ref[1]
+    k0, k1 = key_ref[0, 0], key_ref[1, 0]
 
     def body(n, acc):
         g = counter_gen(kind, k0, k1, n.astype(jnp.uint32), idx)
-        return acc + c_ref[n] * g
+        return acc + c_ref[n, 0] * g
 
     acc = jax.lax.fori_loop(0, b2, body,
                             jnp.zeros((rows, lanes), jnp.float32))
@@ -255,15 +277,14 @@ def zo_replay(x2, key2, coeffs, *, kind="normal", interpret=False,
     assert lanes == LANES and r % block_rows == 0, (x2.shape, block_rows)
     grid = (r // block_rows,)
     spec = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
-    small = lambda shape: pl.BlockSpec(shape, lambda i: (0,))
     return pl.pallas_call(
         functools.partial(_replay_kernel, kind=kind, b2=b2),
         grid=grid,
-        in_specs=[small((2,)), small((b2,)), spec],
+        in_specs=[SMEM, SMEM, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((r, LANES), x2.dtype),
         interpret=interpret,
-    )(key2, coeffs, x2)
+    )(_col(key2, jnp.uint32), _col(coeffs, jnp.float32), x2)
 
 
 # -- zo_dirnorms: per-direction ‖g_n‖² with no direction HBM traffic --------
@@ -273,16 +294,16 @@ def _dirnorm_kernel(key_ref, d_ref, o_ref, *, kind, block_rows):
     n = pl.program_id(0)
     i = pl.program_id(1)
     idx = _block_idx(i, block_rows, LANES)
-    g = counter_gen(kind, key_ref[0], key_ref[1],
+    g = counter_gen(kind, key_ref[0, 0], key_ref[1, 0],
                     jnp.asarray(n).astype(jnp.uint32), idx)
-    g = jnp.where(idx < d_ref[0].astype(jnp.uint32), g, jnp.float32(0.0))
+    g = jnp.where(idx < d_ref[0, 0].astype(jnp.uint32), g, jnp.float32(0.0))
     part = jnp.sum(g * g)
 
     @pl.when(i == 0)
     def _init():
-        o_ref[0] = jnp.float32(0.0)
+        o_ref[n, 0] = jnp.float32(0.0)
 
-    o_ref[0] += part
+    o_ref[n, 0] += part
 
 
 @functools.partial(jax.jit,
@@ -294,17 +315,16 @@ def zo_dirnorms(key2, d, *, b2, n_pad, kind="normal", interpret=False,
 
     d: int32 scalar (valid length; padding indices ≥ d are masked out).
     n_pad: padded total element count (block multiple). HBM traffic is just
-    the [b2] output — directions never leave VMEM.
+    the [b2] output — directions never leave VMEM. The output stays in SMEM
+    for the whole grid and each (n, block) step adds its partial sum.
     """
     assert n_pad % (block_rows * LANES) == 0, (n_pad, block_rows)
     grid = (b2, n_pad // (block_rows * LANES))
-    d_arr = jnp.asarray([d], jnp.int32)
     return pl.pallas_call(
         functools.partial(_dirnorm_kernel, kind=kind, block_rows=block_rows),
         grid=grid,
-        in_specs=[pl.BlockSpec((2,), lambda n, i: (0,)),
-                  pl.BlockSpec((1,), lambda n, i: (0,))],
-        out_specs=pl.BlockSpec((1,), lambda n, i: (n,)),
-        out_shape=jax.ShapeDtypeStruct((b2,), jnp.float32),
+        in_specs=[SMEM, SMEM],
+        out_specs=SMEM,
+        out_shape=jax.ShapeDtypeStruct((b2, 1), jnp.float32),
         interpret=interpret,
-    )(key2, d_arr)
+    )(_col(key2, jnp.uint32), _col(d, jnp.int32)).reshape(b2)

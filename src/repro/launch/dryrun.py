@@ -228,10 +228,7 @@ def run_case(arch, shape_name, *, multi_pod, algo="fedzo", b2=1, h=2,
     mem["total_bytes_per_device"] = (mem["argument_size_in_bytes"] +
                                      mem["temp_size_in_bytes"] +
                                      mem["output_size_in_bytes"])
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # older jax returns [per-device dict]
-        ca = ca[0] if ca else {}
-    ca = dict(ca)
+    ca = dict(compiled.cost_analysis() or {})
     flops = float(ca.get("flops", 0.0))
     bytes_accessed = float(ca.get("bytes accessed", 0.0))
     coll, coll_counts = parse_collectives(compiled.as_text())

@@ -11,16 +11,10 @@ state; the dry-run sets XLA_FLAGS before calling.
 from __future__ import annotations
 
 import jax
-
-try:  # AxisType landed after 0.4.x; Auto is the default behavior there
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(shape))
 

@@ -26,17 +26,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import _act, dense_init, init_mlp, mlp_fwd
 
-try:  # jax.shard_map (with axis_names) landed after 0.4.x
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    def _shard_map(f, *, mesh, in_specs, out_specs, axis_names=None):
-        # pre-AxisType jax: every mesh axis is manual inside shard_map,
-        # which is exactly what the axis_names sets used here request
-        return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs)
-
 
 def init_moe(rng, cfg, dtype):
     d, E, f = cfg.d_model, cfg.n_experts, cfg.moe_d_ff or cfg.d_ff
@@ -191,7 +180,7 @@ def moe_fwd(p, cfg, x, mesh=None, data_axes=None, model_axis="model"):
                         P(model_axis, None, None), P(model_axis, None, None),
                         P(model_axis, None, None))
 
-        out, me, ce = _shard_map(
+        out, me, ce = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=in_specs,

@@ -92,6 +92,12 @@ class Tracer:
                 self._compiled[key] = jitted.lower(*args).compile()
         return self._compiled[key]
 
+    def executables(self) -> list:
+        """The compiled programs, one per static-shape key. Their
+        ``as_text()`` is what the backend compiler emitted (on a TPU, a
+        compiled Pallas kernel shows up as ``tpu_custom_call``)."""
+        return list(self._compiled.values())
+
     def invalidate_compiled(self, key=None):
         """Drop cached executables (all, or one key) — the divergence-
         rollback path re-bakes the backed-off lr into a new program."""

@@ -21,7 +21,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import FedZOConfig
@@ -156,20 +155,20 @@ def make_sharded_round(loss_fn, cfg: FedZOConfig, mesh: Mesh, *,
             chan = (jnp.ones((M,), jnp.bool_) if mask is None else mask)
             w = (jnp.ones((M,), jnp.float32) if weights is None
                  else weights.astype(jnp.float32))
-            agg_flat, sq, losses, maskf, m_div, m_sched = shard_map(
+            agg_flat, sq, losses, maskf, m_div, m_sched = jax.shard_map(
                 shard_body_faults, mesh=mesh,
                 in_specs=(P(), P(), P(axis), P(axis), P(axis), P(axis),
                           P(axis), P(axis)),
                 out_specs=(P(), P(axis), P(axis), P(axis), P(), P()),
-                check_rep=False)(buf0, server_params, client_batches,
+                check_vma=False)(buf0, server_params, client_batches,
                                  client_rngs, chan, w, faults.mask,
                                  faults.corrupt)
         else:
-            agg_flat, sq, losses = shard_map(
+            agg_flat, sq, losses = jax.shard_map(
                 shard_body, mesh=mesh,
                 in_specs=(P(), P(), P(axis), P(axis), P(axis)),
                 out_specs=(P(), P(axis), P(axis)),
-                check_rep=False)(buf0, server_params, client_batches,
+                check_vma=False)(buf0, server_params, client_batches,
                                  client_rngs, maskf)
 
         if use_air:
@@ -180,7 +179,7 @@ def make_sharded_round(loss_fn, cfg: FedZOConfig, mesh: Mesh, *,
             noise_var = sigma_w2 * delta_max / (
                 m_div ** 2 * float(spec.d) * P_TX * cfg.h_min ** 2)
             noise_std = jnp.sqrt(noise_var)
-            agg_flat = kops.zo_walk(agg_flat, jax.random.key_data(noise_rng),
+            agg_flat = kops.zo_walk(agg_flat, kops.key_words(noise_rng),
                                     jnp.zeros((2,), jnp.int32),
                                     jnp.stack([noise_std, jnp.float32(0.0)]),
                                     kind="normal", block_rows=br)

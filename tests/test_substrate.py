@@ -133,3 +133,25 @@ def test_cosine_schedule_monotone_tail():
            for s in range(0, 100, 10)]
     assert lrs[1] >= lrs[0] or lrs[0] < 1e-6  # warmup ramps
     assert lrs[-1] < lrs[2]
+
+
+@pytest.mark.parametrize("env_set", [False, True])
+def test_compile_cache_dir(monkeypatch, env_set):
+    """Entry points keep the compile cache where JAX_COMPILATION_CACHE_DIR
+    says and set no other directory; unset, it goes to <repo>/.jax_cache."""
+    from repro.utils import compile_cache
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = jax.config.jax_compilation_cache_dir
+    if env_set:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert compile_cache.enable() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        return
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        assert compile_cache.enable() == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == os.path.join(
+            repo, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

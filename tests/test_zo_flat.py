@@ -229,3 +229,19 @@ def test_flat_local_phase_and_pod_step_run():
     assert metrics["per_pod_loss"].shape == (2,)
     assert float(metrics["loss"]) > 0
     assert jnp.all(jnp.isfinite(newp["x"]))
+
+
+@pytest.mark.parametrize("impl", ["threefry2x32", "rbg", "unsafe_rbg"])
+def test_kernels_get_two_key_words_under_every_prng_impl(impl):
+    """The kernels read a uint32 [2] key. Threefry keys are exactly that;
+    the rbg family's four-word keys hand over their first two words, so
+    the flat path stays well-defined under every cfg.prng_impl."""
+    key = jax.random.key(5, impl=impl)
+    words = ops.key_words(key)
+    assert words.shape == (2,) and words.dtype == jnp.uint32
+    np.testing.assert_array_equal(np.asarray(words),
+                                  np.asarray(jax.random.key_data(key))[:2])
+    np.testing.assert_array_equal(np.asarray(ops.key_words(words)),
+                                  np.asarray(words))
+    batched = jax.vmap(ops.key_words)(jax.random.split(key, 3))
+    assert batched.shape == (3, 2)
