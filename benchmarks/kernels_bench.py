@@ -18,16 +18,6 @@ def run():
     v = jax.random.normal(jax.random.key(2), (n,), jnp.float32)
     _, us = timed(lambda: ops.axpy2(x, u, v, 0.1, -0.2), n=3)
     rows.append((f"kernels/zo_axpy2_n{n}", us, n * 4 * 4 / max(us, 1e-9)))  # B/µs
-
-    # flat hot-path kernels: same math, directions regenerated in-kernel —
-    # HBM bytes drop from 4 streams (axpy2) to 2 (walk/replay read+write x).
-    # The obs timing harness prints measured µs NEXT TO the HBM-pass model
-    # per kernel (kernels/<name>_us + kernels/<name>_hbm_model_us), so a
-    # kernel regression shows as drift from a constant model column.
-    from repro.obs import kernel_timing
-    for kt in kernel_timing.kernel_report(n=n, b2=20, m=8):
-        rows.extend((f"kernels/{name}", us, derived)
-                    for name, us, derived in kt.rows())
     key2 = jax.random.key_data(jax.random.key(0))
     _, us = timed(lambda: ops.zo_dirnorms(key2, n - 7, b2=20, n_pad=n), n=3)
     rows.append((f"kernels/zo_dirnorms_n{n}_b2_20", us, 20 * 4 / max(us, 1e-9)))

@@ -1,11 +1,8 @@
 """Benchmark harness: one entry per paper table/figure (DESIGN.md §6).
 
-Prints ``name,us_per_call,derived`` CSV, and persists every suite's rows
-to ``results/BENCH_<suite>.json`` (``obs.save_bench``): the previous
-snapshot is pushed onto the file's bounded ``history`` list, so the perf
-trajectory accumulates per run — render it with
-``python results/make_tables.py --bench``. ``--no-save`` keeps a run
-print-only; ``--out-dir`` redirects the snapshots.
+Prints ``name,us_per_call,derived`` CSV rows. These are host-clock
+timings of whatever backend runs them (the CPU interpreter for the Pallas
+kernels off a TPU); the on-chip record is ``bench/run.py``.
 
 ``--quick`` runs the kernel, ZO-path, round-engine, and roofline benches;
 default additionally runs the paper-figure suites (≈10-20 min on CPU).
@@ -21,10 +18,6 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default="")
-    ap.add_argument("--no-save", action="store_true",
-                    help="don't snapshot rows to results/BENCH_*.json")
-    ap.add_argument("--out-dir", default=None,
-                    help="snapshot directory (default: results/)")
     args = ap.parse_args()
 
     from repro.utils import compile_cache
@@ -53,21 +46,12 @@ def main() -> None:
         if args.only and args.only != tag:
             continue
         try:
-            rows = list(fn())
-            for name, us, derived in rows:
+            for name, us, derived in fn():
                 print(f"{name},{us:.1f},{derived}", flush=True)
         except Exception:  # noqa: BLE001
             failed = True
             print(f"{tag}/ERROR,0,nan", flush=True)
             traceback.print_exc(file=sys.stderr)
-            continue
-        if not args.no_save:
-            try:
-                from repro import obs
-                obs.save_bench(tag, rows, out_dir=args.out_dir,
-                               config={"quick": args.quick})
-            except Exception:  # noqa: BLE001 — a snapshot failure must
-                traceback.print_exc(file=sys.stderr)  # not fail the bench
     sys.exit(1 if failed else 0)
 
 

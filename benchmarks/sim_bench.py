@@ -22,7 +22,7 @@ Rows:
   inside the engine over the process's own n devices (the most that divide
   M). The CPU sharding check on forced host devices lives in
   ``tests/test_sim.py``.
-- ``tiered/*`` (``run_tiered``, snapshot ``BENCH_tiered.json``) — the
+- ``tiered/*`` (``run_tiered``) — the
   host-resident HostStore streaming engine vs the resident scan on the
   same experiment, plus an N=100k-client CPU run with prefetch-stall and
   host/device residency accounting (DESIGN.md §15).
@@ -172,9 +172,7 @@ ALGO_VARIANTS = (
 def run_algos():
     """Per-strategy engine cost: µs/round for each registered ZO strategy
     (+ the surrogate estimator) on the quickstart experiment under the fast
-    engine plan, plus its overhead vs plain FedZO in %. (The harness —
-    benchmarks/run.py — snapshots these rows to ``results/BENCH_algos.json``
-    via ``obs.save_bench``, same as every other suite.)"""
+    engine plan, plus its overhead vs plain FedZO in %."""
     import dataclasses
 
     from repro import sim
@@ -211,8 +209,7 @@ def run_algos():
 def run_scenario():
     """Wireless-scenario engine cost (DESIGN.md §16): the correlated-fading
     chain and energy-gated participation vs the channel-off i.i.d. draw on
-    the quickstart experiment under channel scheduling. Rows (snapshot
-    ``results/BENCH_scenario.json`` via the harness):
+    the quickstart experiment under channel scheduling. Rows:
 
     - ``scenario/channel_off_us_per_round`` — i.i.d. per-round draw (the
       paper's Sec. IV-A baseline) under the fast engine plan.

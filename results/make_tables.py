@@ -1,12 +1,5 @@
 """Render the EXPERIMENTS.md §Dry-run / §Roofline tables from the dry-run
-jsonl records. Usage: python results/make_tables.py > results/tables.md
-
-``--bench`` instead renders the persisted benchmark trajectory from the
-``results/BENCH_*.json`` snapshots (written by ``benchmarks/run.py`` via
-``obs.save_bench``): one table per suite, the current rows beside the same
-rows at each retained history point (newest last), so per-PR perf drift
-reads straight off the row."""
-import argparse
+jsonl records. Usage: python results/make_tables.py > results/tables.md"""
 import glob
 import json
 import os
@@ -39,65 +32,7 @@ def s3(x):
     return f"{x:.4f}" if x >= 1e-4 else f"{x:.2e}"
 
 
-def _fmt_us(v):
-    return f"{v:.1f}" if isinstance(v, (int, float)) else "—"
-
-
-def _fmt_derived(v):
-    if isinstance(v, float):
-        return f"{v:.4g}"
-    return "—" if v is None else str(v)
-
-
-def bench_tables(out_dir=None):
-    """The BENCH_*.json perf trajectory as markdown: per suite, a table of
-    ``row | <older snapshots µs...> | current µs | derived`` — the µs
-    trajectory of every benchmark row, oldest history column first."""
-    try:
-        from repro.obs import bench as obs_bench
-        snaps = obs_bench.load_benches(out_dir or HERE)
-    except ImportError:
-        # repro not importable (e.g. bare results/ checkout): read raw
-        snaps = {}
-        for p in sorted(glob.glob(os.path.join(out_dir or HERE,
-                                               "BENCH_*.json"))):
-            with open(p) as f:
-                snap = json.load(f)
-            snaps[snap.get("suite",
-                           os.path.basename(p)[6:-5])] = snap
-    if not snaps:
-        print("no BENCH_*.json snapshots found — run "
-              "`python -m benchmarks.run --quick` first")
-        return
-    for suite, snap in snaps.items():
-        hist = snap.get("history", [])
-        print(f"### Bench trajectory: {suite} "
-              f"(jax {snap.get('jax_version')}, "
-              f"{len(hist)} history point(s))\n")
-        cols = [f"t-{len(hist) - i}" for i in range(len(hist))] + ["now"]
-        print("| row | " + " µs | ".join(cols) + " µs | derived (now) |")
-        print("|---" * (len(cols) + 2) + "|")
-        rows_now = {r["name"]: r for r in snap.get("rows", [])}
-        points = [{r["name"]: r for r in h.get("rows") or []}
-                  for h in hist] + [rows_now]
-        for name in rows_now:
-            cells = [_fmt_us(pt[name]["us_per_call"]) if name in pt
-                     else "—" for pt in points]
-            print(f"| {name} | " + " | ".join(cells) +
-                  f" | {_fmt_derived(rows_now[name].get('derived'))} |")
-        print()
-
-
 def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--bench", action="store_true",
-                    help="render the BENCH_*.json perf trajectory instead")
-    ap.add_argument("--out-dir", default=None,
-                    help="snapshot directory (default: results/)")
-    args = ap.parse_args()
-    if args.bench:
-        bench_tables(args.out_dir)
-        return
     best = load()
     print("### Dry-run matrix (compile status, per-device memory)\n")
     print("| arch | shape | 16×16 mem GiB (fits?) | 2×16×16 mem GiB (fits?) |")

@@ -48,6 +48,7 @@ import jax.numpy as jnp
 
 from repro.kernels import ops as kops
 from repro.kernels.zo_axpy import counter_direction_flat
+from repro.obs.trace import scope
 from repro.utils.flatparams import FlatSpec, flat_spec, unflatten
 from repro.utils.tree import (normal_like_tree, sphere_like_tree,
                               tree_add_normal, tree_axpy, tree_norm,
@@ -107,6 +108,18 @@ def counter_direction(rng, n, params, kind, dtype=jnp.float32):
     return jax.tree.unflatten(spec.treedef, out)
 
 
+def query(loss_fn, buf, spec: FlatSpec, batch):
+    """One loss query of a flat buffer: unflatten plus the model's forward,
+    under the ``fedzo.query`` scope."""
+    with scope("fedzo.query"):
+        return loss_fn(unflatten(buf, spec), batch)
+
+
+def _query_tree(loss_fn, params, batch):
+    with scope("fedzo.query"):
+        return loss_fn(params, batch)
+
+
 def _direction(rng, n, params, kind, dtype, conv):
     if conv == "counter":
         return counter_direction(rng, n, params, kind, dtype)
@@ -144,16 +157,17 @@ def coefficients(loss_fn, params, batch, rng, *, mu, b2, kind="sphere",
     """
     d = tree_size(params)
     scale = _scale_factor(d, kind)
-    base = loss_fn(params, batch) if base_loss is None else base_loss
+    base = (_query_tree(loss_fn, params, batch) if base_loss is None
+            else base_loss)
 
     def body(n, acc):
         # materialized direction + axpy measured Pareto-best on the XLA:CPU
         # buffer-assignment instrument (§Perf iteration 3: two-pass
         # streaming, chunked and rbg variants all refuted).
         v = _direction(rng, n, params, kind, direction_dtype, conv)
-        lp = loss_fn(tree_axpy(mu, v, params), batch)
+        lp = _query_tree(loss_fn, tree_axpy(mu, v, params), batch)
         if central:
-            lm = loss_fn(tree_axpy(-mu, v, params), batch)
+            lm = _query_tree(loss_fn, tree_axpy(-mu, v, params), batch)
             c = scale * (lp - lm).astype(jnp.float32) / (2 * mu)
         else:
             c = scale * (lp - base).astype(jnp.float32) / mu
@@ -211,7 +225,7 @@ def flat_coefficients(loss_fn, buf, spec: FlatSpec, batch, rng, *, mu, b2,
         raise ValueError(f"flat path does not support kind={kind!r}")
     key2 = kops.key_words(rng)
     scale = _scale_factor(spec.d, kind)
-    base = (loss_fn(unflatten(buf, spec), batch)
+    base = (query(loss_fn, buf, spec, batch)
             if base_loss is None else base_loss)
     if inv is None:
         inv = flat_inv_norms(key2, spec, b2, kind, interpret=interpret,
@@ -228,13 +242,13 @@ def flat_coefficients(loss_fn, buf, spec: FlatSpec, batch, rng, *, mu, b2,
         xp = kops.zo_walk(xp, key2, jnp.stack([prev, n]), jnp.stack([a, b]),
                           kind=ck, interpret=interpret,
                           block_rows=block_rows)
-        lp = loss_fn(unflatten(xp, spec), batch)
+        lp = query(loss_fn, xp, spec, batch)
         if central:
             xp = kops.zo_walk(xp, key2, jnp.stack([n, n]),
                               jnp.stack([-2 * mu * inv[n], jnp.float32(0.0)]),
                               kind=ck, interpret=interpret,
                               block_rows=block_rows)
-            lm = loss_fn(unflatten(xp, spec), batch)
+            lm = query(loss_fn, xp, spec, batch)
             c = scale * (lp - lm).astype(jnp.float32) / (2 * mu)
         else:
             c = scale * (lp - base).astype(jnp.float32) / mu

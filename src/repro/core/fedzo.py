@@ -40,6 +40,7 @@ from repro.core import estimator
 from repro.core.aircomp import (aircomp_aggregate, aircomp_aggregate_flat,
                                 mask_stats, schedule_by_channel)
 from repro.kernels import ops as kops
+from repro.obs.trace import scope
 from repro.utils.flatparams import (flat_geometry, flat_spec, flatten,
                                     unflatten)
 from repro.utils.tree import tree_add, tree_scale, tree_sub
@@ -171,12 +172,12 @@ def _surrogate_phase_scan(loss_fn, buf0, spec, keys, batches, cfg):
         k, batch = inp
         V, inv = estimator.direction_block(k, spec, b2q, kind=cfg.estimator,
                                            conv="block")
-        base = loss_fn(unflatten(buf, spec), batch)
-        lp = jax.vmap(lambda v, s: loss_fn(
-            unflatten(buf + (mu * s) * v, spec), batch))(V, inv)
+        base = estimator.query(loss_fn, buf, spec, batch)
+        lp = jax.vmap(lambda v, s: estimator.query(
+            loss_fn, buf + (mu * s) * v, spec, batch))(V, inv)
         if cfg.central:
-            lm = jax.vmap(lambda v, s: loss_fn(
-                unflatten(buf - (mu * s) * v, spec), batch))(V, inv)
+            lm = jax.vmap(lambda v, s: estimator.query(
+                loss_fn, buf - (mu * s) * v, spec, batch))(V, inv)
             coeffs = scale * (lp - lm).astype(jnp.float32) / (2 * mu)
         else:
             coeffs = scale * (lp - base).astype(jnp.float32) / mu
@@ -220,12 +221,12 @@ def _wide_phase_scan(loss_fn, buf0, spec, keys, batches, cfg, like=None):
         V, inv = estimator.direction_block(k, spec, cfg.b2,
                                            kind=cfg.estimator, conv=conv,
                                            like=like)
-        base = loss_fn(unflatten(buf, spec), batch)
-        lp = jax.vmap(lambda v, s: loss_fn(
-            unflatten(buf + (mu * s) * v, spec), batch))(V, inv)
+        base = estimator.query(loss_fn, buf, spec, batch)
+        lp = jax.vmap(lambda v, s: estimator.query(
+            loss_fn, buf + (mu * s) * v, spec, batch))(V, inv)
         if cfg.central:
-            lm = jax.vmap(lambda v, s: loss_fn(
-                unflatten(buf - (mu * s) * v, spec), batch))(V, inv)
+            lm = jax.vmap(lambda v, s: estimator.query(
+                loss_fn, buf - (mu * s) * v, spec, batch))(V, inv)
             coeffs = scale * (lp - lm).astype(jnp.float32) / (2 * mu)
         else:
             coeffs = scale * (lp - base).astype(jnp.float32) / mu
@@ -344,104 +345,117 @@ def round_simulated(loss_fn, server_params, client_batches, client_rngs,
     noise_rng = channel_rng
     air_stats = {}
     if cfg.channel_schedule and channel_rng is not None:
-        k_sched, noise_rng = jax.random.split(channel_rng)
-        if channel is None:
-            _, mask = schedule_by_channel(k_sched, M, cfg.h_min)
+        with scope("fedzo.cohort"):
+            k_sched, noise_rng = jax.random.split(channel_rng)
+            if channel is None:
+                _, mask = schedule_by_channel(k_sched, M, cfg.h_min)
     if channel is not None:
         # the scenario engine realized this round's channel already:
         # correlated-fading scheduling ∧ battery gating (sim/channel.py)
         mask = channel.mask
 
     if cfg.flat_params or cfg.batch_directions:
-        spec, br = (_wide_setup(server_params, cfg) if cfg.batch_directions
-                    else _flat_setup(server_params, cfg))
-        buf0 = flatten(server_params, spec)
-        keys = jax.vmap(lambda r: jax.random.split(r, cfg.local_iters))(
-            client_rngs)
+        with scope("fedzo.local"):
+            spec, br = (_wide_setup(server_params, cfg)
+                        if cfg.batch_directions
+                        else _flat_setup(server_params, cfg))
+            buf0 = flatten(server_params, spec)
+            keys = jax.vmap(lambda r: jax.random.split(r, cfg.local_iters))(
+                client_rngs)
 
-        def one_client(batches, ks, cst=None):
-            lf = loss_wrap(loss_fn, cst) if loss_wrap is not None else loss_fn
-            if cfg.batch_directions:
-                buf, _, base = _wide_phase_scan(lf, buf0, spec, ks, batches,
-                                                cfg, like=server_params)
+            def one_client(batches, ks, cst=None):
+                lf = (loss_wrap(loss_fn, cst) if loss_wrap is not None
+                      else loss_fn)
+                if cfg.batch_directions:
+                    buf, _, base = _wide_phase_scan(lf, buf0, spec, ks,
+                                                    batches, cfg,
+                                                    like=server_params)
+                else:
+                    buf, _, base = _flat_phase_scan(lf, buf0, spec, br, ks,
+                                                    batches, cfg)
+                return buf - buf0, base
+
+            if cstate is not None:
+                deltas, losses = jax.vmap(one_client)(client_batches, keys,
+                                                      cstate)
             else:
-                buf, _, base = _flat_phase_scan(lf, buf0, spec, br, ks,
-                                                batches, cfg)
-            return buf - buf0, base
+                deltas, losses = jax.vmap(one_client)(client_batches, keys)
 
-        if cstate is not None:
-            deltas, losses = jax.vmap(one_client)(client_batches, keys,
-                                                  cstate)
-        else:
-            deltas, losses = jax.vmap(one_client)(client_batches, keys)
+        with scope("fedzo.aggregate"):
+            if state_fn is not None:
+                deltas, new_cstate = state_fn(deltas, cstate, spec)
 
-        if state_fn is not None:
-            deltas, new_cstate = state_fn(deltas, cstate, spec)
+            if faults is not None:
+                deltas, fmask = faults.apply_flat(deltas)
+                mask = fmask if mask is None else mask & fmask
 
-        if faults is not None:
-            deltas, fmask = faults.apply_flat(deltas)
-            mask = fmask if mask is None else mask & fmask
-
-        if cfg.aircomp and channel_rng is not None:
-            agg_flat, air_stats = aircomp_aggregate_flat(
-                deltas, noise_rng, snr_db=cfg.snr_db, h_min=cfg.h_min,
-                d=spec.d, mask=mask, weights=weights, block_rows=br)
-        elif mask is not None or weights is not None:
-            maskf, m_div, m_sched = mask_stats(mask, M, weights)
-            agg_flat = jnp.einsum("mn,m->n", deltas, maskf) / m_div
-            # m_effective reports unconditionally: a weighted-but-
-            # unscheduled round must carry the same cohort-size column as
-            # every other aggregation path (history/CSV consistency)
-            air_stats = {"m_effective": m_sched}
-        else:
-            agg_flat = jnp.mean(deltas, axis=0)
-        agg = unflatten(agg_flat, spec)
+            if cfg.aircomp and channel_rng is not None:
+                agg_flat, air_stats = aircomp_aggregate_flat(
+                    deltas, noise_rng, snr_db=cfg.snr_db, h_min=cfg.h_min,
+                    d=spec.d, mask=mask, weights=weights, block_rows=br)
+            elif mask is not None or weights is not None:
+                maskf, m_div, m_sched = mask_stats(mask, M, weights)
+                agg_flat = jnp.einsum("mn,m->n", deltas, maskf) / m_div
+                # m_effective reports unconditionally: a weighted-but-
+                # unscheduled round must carry the same cohort-size column
+                # as every other aggregation path (history/CSV consistency)
+                air_stats = {"m_effective": m_sched}
+            else:
+                agg_flat = jnp.mean(deltas, axis=0)
+            agg = unflatten(agg_flat, spec)
     else:
         def one_client(batches, rng, cst=None):
             lf = loss_wrap(loss_fn, cst) if loss_wrap is not None else loss_fn
             delta, res = client_delta(lf, server_params, batches, rng, cfg)
             return delta, res.losses
 
-        if cstate is not None:
-            deltas, losses = jax.vmap(one_client)(client_batches,
-                                                  client_rngs, cstate)
-        else:
-            deltas, losses = jax.vmap(one_client)(client_batches, client_rngs)
+        with scope("fedzo.local"):
+            if cstate is not None:
+                deltas, losses = jax.vmap(one_client)(client_batches,
+                                                      client_rngs, cstate)
+            else:
+                deltas, losses = jax.vmap(one_client)(client_batches,
+                                                      client_rngs)
 
-        if state_fn is not None:
-            deltas, new_cstate = state_fn(deltas, cstate, None)
+        with scope("fedzo.aggregate"):
+            if state_fn is not None:
+                deltas, new_cstate = state_fn(deltas, cstate, None)
 
+            if faults is not None:
+                deltas, fmask = faults.apply_tree(deltas)
+                mask = fmask if mask is None else mask & fmask
+
+            if cfg.aircomp and channel_rng is not None:
+                agg, air_stats = aircomp_aggregate(
+                    deltas, noise_rng, snr_db=cfg.snr_db, h_min=cfg.h_min,
+                    mask=mask, weights=weights)
+            elif mask is not None or weights is not None:
+                maskf, m_div, m_sched = mask_stats(mask, M, weights)
+                agg = jax.tree.map(
+                    lambda x: (jnp.einsum("m...,m->...",
+                                          x.astype(jnp.float32),
+                                          maskf) / m_div).astype(x.dtype),
+                    deltas)
+                air_stats = {"m_effective": m_sched}  # see flat-path comment
+            else:
+                agg = tree_scale(1.0 / M,
+                                 jax.tree.map(lambda x: jnp.sum(x, 0),
+                                              deltas))
+
+    with scope("fedzo.aggregate"):
+        if momentum is not None and cfg.server_momentum > 0:
+            momentum = jax.tree.map(
+                lambda m, g: (cfg.server_momentum * m + g).astype(m.dtype),
+                momentum, agg)
+            agg = momentum
+        new_params = tree_add(server_params, agg)
         if faults is not None:
-            deltas, fmask = faults.apply_tree(deltas)
-            mask = fmask if mask is None else mask & fmask
-
-        if cfg.aircomp and channel_rng is not None:
-            agg, air_stats = aircomp_aggregate(
-                deltas, noise_rng, snr_db=cfg.snr_db, h_min=cfg.h_min,
-                mask=mask, weights=weights)
-        elif mask is not None or weights is not None:
-            maskf, m_div, m_sched = mask_stats(mask, M, weights)
-            agg = jax.tree.map(
-                lambda x: (jnp.einsum("m...,m->...", x.astype(jnp.float32),
-                                      maskf) / m_div).astype(x.dtype),
-                deltas)
-            air_stats = {"m_effective": m_sched}  # see flat-path comment
-        else:
-            agg = tree_scale(1.0 / M,
-                             jax.tree.map(lambda x: jnp.sum(x, 0), deltas))
-
-    if momentum is not None and cfg.server_momentum > 0:
-        momentum = jax.tree.map(
-            lambda m, g: (cfg.server_momentum * m + g).astype(m.dtype),
-            momentum, agg)
-        agg = momentum
-    new_params = tree_add(server_params, agg)
-    if faults is not None:
-        # mask is never None under faults, so every branch above reported
-        # m_effective (the surviving cohort); add the poison count
-        air_stats["m_corrupt"] = faults.n_corrupt
-    metrics = {"mean_local_loss": jnp.mean(losses),
-               "first_loss": jnp.mean(losses[:, 0]), **air_stats}
+            # mask is never None under faults, so every branch above
+            # reported m_effective (the surviving cohort); add the poison
+            # count
+            air_stats["m_corrupt"] = faults.n_corrupt
+        metrics = {"mean_local_loss": jnp.mean(losses),
+                   "first_loss": jnp.mean(losses[:, 0]), **air_stats}
     out = (new_params, metrics)
     if momentum is not None:
         out = out + (momentum,)

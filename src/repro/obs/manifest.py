@@ -11,8 +11,7 @@ the run produced.
 
 ``sim.run_experiment`` emits one alongside durable checkpoints
 (``<checkpoint_dir>/manifest.json``) and next to a file-backed metric sink
-(``<sink>.manifest.json``); benchmark snapshots embed the same provenance
-block (obs/bench.py).
+(``<sink>.manifest.json``).
 """
 from __future__ import annotations
 

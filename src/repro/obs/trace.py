@@ -18,7 +18,17 @@ across segments).
 ``Tracer(profile_dir=...)`` additionally wraps the run in a
 ``jax.profiler`` trace (one ``start_trace``/``stop_trace`` pair), so the
 same handle that gives coarse spans can drop a full XLA profile for
-perfetto/tensorboard when you need the microscope.
+perfetto/tensorboard when you need the microscope. Every span is also a
+``jax.profiler.TraceAnnotation`` of the same name and meta, so the host
+spans sit on the profiler's clock beside the device's ops and name the
+gaps between them (about 1 µs a span when no profile is running).
+
+The device half is ``SCOPES``: the named scopes the round implementations
+compile into their programs (``scope(name)`` around each layer of a
+round). They are metadata on the compiled HLO (each instruction's
+``op_name`` path), so they move no bits; a profiler trace carries each
+op's path as the ``tf_op`` stat of its event metadata, which maps the
+trace's ops back to them (``bench/scope_reduce.py``).
 """
 from __future__ import annotations
 
@@ -26,6 +36,29 @@ import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Optional
+
+import jax
+
+# The round's layers, as named scopes in the compiled program:
+# - fedzo.cohort: the round's realization (participants, minibatches, size
+#   weights, fault and channel draws);
+# - fedzo.local: the M vmapped local phases, the ZO kernels included;
+# - fedzo.query: one loss query (unflatten + the model's forward), nested
+#   in fedzo.local;
+# - fedzo.aggregate: client deltas to new params (delta correction, fault
+#   scrub, the mean or AirComp with its noise, the mesh psum, momentum);
+# - fedzo.eval: the in-scan eval.
+# Ops under none of them are the scan's own bookkeeping.
+SCOPES = ("fedzo.cohort", "fedzo.local", "fedzo.query", "fedzo.aggregate",
+          "fedzo.eval")
+
+
+def scope(name: str):
+    """``jax.named_scope(name)`` for one of the registered ``SCOPES``."""
+    if name not in SCOPES:
+        raise ValueError(f"unregistered scope {name!r}; registered: "
+                         f"{SCOPES}")
+    return jax.named_scope(name)
 
 
 @dataclass
@@ -53,6 +86,7 @@ class Tracer:
 
     @contextmanager
     def span(self, name: str, **meta):
+        """A nested host span, also a profiler annotation with its meta."""
         idx = len(self.spans)
         s = Span(name=name, start=time.perf_counter(),
                  depth=len(self._stack),
@@ -61,7 +95,8 @@ class Tracer:
         self.spans.append(s)
         self._stack.append(idx)
         try:
-            yield s
+            with jax.profiler.TraceAnnotation(name, **meta):
+                yield s
         finally:
             s.duration = time.perf_counter() - s.start
             self._stack.pop()
@@ -73,7 +108,6 @@ class Tracer:
         if not self.profile_dir:
             yield
             return
-        import jax.profiler
         jax.profiler.start_trace(self.profile_dir)
         try:
             with self.span("jax_profile", trace_dir=self.profile_dir):

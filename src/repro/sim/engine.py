@@ -73,6 +73,7 @@ from repro.core.strategy import _static_positive  # noqa: F401  (re-export)
 from repro.obs import manifest as obs_manifest
 from repro.obs.ledger import CommsLedger
 from repro.obs.taps import RoundTap
+from repro.obs.trace import scope
 from repro.sim import channel as channel_lib
 from repro.sim.channel import RoundChannel
 from repro.sim.faults import DivergenceError, FaultModel
@@ -154,23 +155,25 @@ def make_round_step(loss_fn, cfg: FedZOConfig, *, algo: Optional[str] = None,
         key, k_part, k_batch, k_zo, k_chan, k_fault, k_chanm = \
             split_round_keys(key, faults=faults is not None,
                              channel=channel is not None)
-        idx = sample_participants(k_part, store.n_clients,
-                                  cfg.n_participating)
-        batches = sample_batches(store, idx, k_batch, cfg.local_iters,
-                                 cfg.b1)
-        # FedAvg-style n_i/n weights of the sampled clients (mean-1
-        # normalized); only added to the round call when enabled so custom
-        # round_fns without a weights kwarg keep working — the per-round
-        # fault realization and channel realization ride the same pattern
-        wkw = ({"weights": aircomp.size_weights(store.sizes[idx])}
-               if weigh else {})
-        if faults is not None:
-            fstate, inj = faults.step(k_fault, fstate, idx)
-            wkw["faults"] = inj
-        if channel is not None:
-            cstate, wkw["channel"] = channel.step(
-                k_chanm, cstate, idx, h_min=cfg.h_min,
-                schedule=cfg.channel_schedule)
+        with scope("fedzo.cohort"):
+            idx = sample_participants(k_part, store.n_clients,
+                                      cfg.n_participating)
+            batches = sample_batches(store, idx, k_batch, cfg.local_iters,
+                                     cfg.b1)
+            # FedAvg-style n_i/n weights of the sampled clients (mean-1
+            # normalized); only added to the round call when enabled so
+            # custom round_fns without a weights kwarg keep working — the
+            # per-round fault and channel realizations ride the same
+            # pattern
+            wkw = ({"weights": aircomp.size_weights(store.sizes[idx])}
+                   if weigh else {})
+            if faults is not None:
+                fstate, inj = faults.step(k_fault, fstate, idx)
+                wkw["faults"] = inj
+            if channel is not None:
+                cstate, wkw["channel"] = channel.step(
+                    k_chanm, cstate, idx, h_min=cfg.h_min,
+                    schedule=cfg.channel_schedule)
         params, metrics, momentum, zstate = strat.run_round(
             loss_fn, params, batches, k_zo, cfg, channel_rng=k_chan,
             momentum=momentum, zstate=zstate, idx=idx, round_fn=round_fn,
@@ -227,18 +230,19 @@ def make_cohort_round_step(loss_fn, cfg: FedZOConfig, *,
             split_round_keys(key, faults=faults is not None,
                              channel=channel is not None)
         del k_part, k_chanm  # consumed host-side by the CohortStream replay
-        batches = sample_cohort_batches(cohort.data, cohort.sizes, k_batch,
-                                        cfg.local_iters, cfg.b1)
-        # cohort.sizes IS store.sizes[idx] (staged by the stream), so the
-        # weights match the resident round bit-for-bit
-        wkw = ({"weights": aircomp.size_weights(cohort.sizes)}
-               if weigh else {})
-        if faults is not None:
-            wkw["faults"] = faults.realize(k_fault, cohort.avail)
-        if channel is not None:
-            wkw["channel"] = RoundChannel(model=channel, h=cohort.chan_h,
-                                          mask=cohort.chan_mask)
-        idx = jnp.arange(cohort.sizes.shape[0], dtype=jnp.int32)
+        with scope("fedzo.cohort"):
+            batches = sample_cohort_batches(cohort.data, cohort.sizes,
+                                            k_batch, cfg.local_iters, cfg.b1)
+            # cohort.sizes IS store.sizes[idx] (staged by the stream), so
+            # the weights match the resident round bit-for-bit
+            wkw = ({"weights": aircomp.size_weights(cohort.sizes)}
+                   if weigh else {})
+            if faults is not None:
+                wkw["faults"] = faults.realize(k_fault, cohort.avail)
+            if channel is not None:
+                wkw["channel"] = RoundChannel(model=channel, h=cohort.chan_h,
+                                              mask=cohort.chan_mask)
+            idx = jnp.arange(cohort.sizes.shape[0], dtype=jnp.int32)
         params, metrics, momentum, zstate = strat.run_round(
             loss_fn, params, batches, k_zo, cfg, channel_rng=k_chan,
             momentum=momentum, zstate=zstate, idx=idx, round_fn=round_fn,
@@ -345,9 +349,10 @@ def _scan_rounds(step, state0, ring, ebuf, ts, xs=None, *, ring_alloc,
         if do_eval:
             def run_eval(args):
                 buf, p = args
-                vals = eval_fn(p)
-                return {k: buf[k].at[t // eval_every].set(
-                    vals[k].astype(buf[k].dtype)) for k in buf}
+                with scope("fedzo.eval"):
+                    vals = eval_fn(p)
+                    return {k: buf[k].at[t // eval_every].set(
+                        vals[k].astype(buf[k].dtype)) for k in buf}
 
             ebuf = jax.lax.cond(jnp.mod(t, eval_every) == 0, run_eval,
                                 lambda args: args[0], (ebuf, state[0]))
@@ -772,6 +777,10 @@ def _run_checkpointed(loss_fn, params, store, cfg, rounds, *, strategy,
                 donate_argnums=(0, 1, 2, 3, 4, 5, 6, 7) if donate else ())
         return seg_fns[chunk]
 
+    def span(name, **meta):
+        return (tracer.span(name, **meta) if tracer is not None
+                else nullcontext())
+
     retries, segments_done = 0, 0
     with (tracer.profile() if tracer is not None else nullcontext()):
         while t < rounds:
@@ -784,38 +793,44 @@ def _run_checkpointed(loss_fn, params, store, cfg, rounds, *, strategy,
                 # executable across same-shape segments
                 run = tracer.timed_compile(
                     ("segment", chunk, cur_lr, orig_hash), jitted, *args)
-                seg_span = tracer.span("segment", t0=t, chunk=chunk)
             else:
-                run, seg_span = jitted, nullcontext()
-            with seg_span:
-                out = run(*args)
+                run = jitted
+            # the segment's host phases, each named on the profiler clock
+            # by the segment's first round (the index its ring rows carry)
+            t0 = t
+            with span("segment", t0=t0, chunk=chunk):
+                with span("segment.dispatch", t0=t0):
+                    out = run(*args)
                 # ONE host sync per segment: fetch the full carry, then
                 # everything below (divergence check + atomic save) is
                 # host-side numpy
-                state = jax.device_get(_carry_to_state(*out))
-            t_next = t + chunk
-            if not _finite_state(state, range(t, t_next), ring_alloc,
-                                 eval_every, do_eval):
-                retries += 1
-                if retries > max_retries:
-                    raise DivergenceError(t_next, max_retries, cur_lr)
-                cur_lr *= lr_backoff
-                events.append({"round": t_next, "event": "rollback",
-                               "from_round": t, "retry": retries,
-                               "lr": cur_lr})
-                seg_fns.clear()  # the backed-off lr is baked into the
-                if tracer is not None:   # program (and its executable)
-                    tracer.invalidate_compiled()
-                snap = ckpt.latest_run_state(checkpoint_dir)
-                good, _ = ckpt.restore_run_state(snap, state)
-                params, momentum, key, fstate, cstate, zstate, ring, \
-                    ebuf = _state_to_carry(good, cfg)
-                continue
-            retries = 0
-            params, momentum, key, fstate, cstate, zstate, ring, ebuf = out
-            t = t_next
-            ckpt.save_run_state(checkpoint_dir, state, round_idx=t,
-                                meta=checkpoint_meta())
+                with span("segment.fetch", t0=t0):
+                    state = jax.device_get(_carry_to_state(*out))
+                t_next = t + chunk
+                if not _finite_state(state, range(t, t_next), ring_alloc,
+                                     eval_every, do_eval):
+                    retries += 1
+                    if retries > max_retries:
+                        raise DivergenceError(t_next, max_retries, cur_lr)
+                    cur_lr *= lr_backoff
+                    events.append({"round": t_next, "event": "rollback",
+                                   "from_round": t, "retry": retries,
+                                   "lr": cur_lr})
+                    seg_fns.clear()  # the backed-off lr is baked into the
+                    if tracer is not None:   # program (and its executable)
+                        tracer.invalidate_compiled()
+                    snap = ckpt.latest_run_state(checkpoint_dir)
+                    good, _ = ckpt.restore_run_state(snap, state)
+                    params, momentum, key, fstate, cstate, zstate, ring, \
+                        ebuf = _state_to_carry(good, cfg)
+                    continue
+                retries = 0
+                params, momentum, key, fstate, cstate, zstate, ring, ebuf = \
+                    out
+                t = t_next
+                with span("checkpoint.save", t0=t0):
+                    ckpt.save_run_state(checkpoint_dir, state, round_idx=t,
+                                        meta=checkpoint_meta())
             segments_done += 1
             if segment_callback is not None:
                 segment_callback(t, rounds)

@@ -29,6 +29,7 @@ from repro.core.fedzo import (_flat_phase_scan, _flat_setup,
                               _wide_phase_scan, _wide_setup)
 from repro.kernels import ops as kops
 from repro.launch.mesh import make_clients_mesh  # noqa: F401  (re-export)
+from repro.obs.trace import scope
 from repro.utils.flatparams import flatten, unflatten
 from repro.utils.tree import tree_add
 
@@ -74,21 +75,25 @@ def make_sharded_round(loss_fn, cfg: FedZOConfig, mesh: Mesh, *,
         if M % n_dev:
             raise ValueError(f"n_participating={M} must divide evenly over "
                              f"the {n_dev}-device '{axis}' mesh axis")
-        spec, br = (_wide_setup(server_params, cfg) if cfg.batch_directions
-                    else _flat_setup(server_params, cfg))
-        buf0 = flatten(server_params, spec)
+        with scope("fedzo.local"):
+            spec, br = (_wide_setup(server_params, cfg)
+                        if cfg.batch_directions
+                        else _flat_setup(server_params, cfg))
+            buf0 = flatten(server_params, spec)
 
         mask = None
         noise_rng = channel_rng
         air_stats = {}
         if cfg.channel_schedule and channel_rng is not None:
-            k_sched, noise_rng = jax.random.split(channel_rng)
-            _, mask = schedule_by_channel(k_sched, M, cfg.h_min)
+            with scope("fedzo.cohort"):
+                k_sched, noise_rng = jax.random.split(channel_rng)
+                _, mask = schedule_by_channel(k_sched, M, cfg.h_min)
         use_air = cfg.aircomp and channel_rng is not None
         # size weighting rides the same per-row coefficient vector the mask
         # does, so the weighted round shards identically to the masked one
         use_rowcoef = mask is not None or weights is not None
-        maskf, m_div, m_sched = mask_stats(mask, M, weights)
+        with scope("fedzo.aggregate"):
+            maskf, m_div, m_sched = mask_stats(mask, M, weights)
 
         def local_deltas(b0, params, batches_l, rngs_l):
             keys = jax.vmap(lambda r: jax.random.split(
@@ -109,20 +114,23 @@ def make_sharded_round(loss_fn, cfg: FedZOConfig, mesh: Mesh, *,
             return jax.vmap(one_client)(batches_l, keys)
 
         def shard_body(b0, params, batches_l, rngs_l, maskf_l):
-            deltas_l, losses_l = local_deltas(b0, params, batches_l, rngs_l)
+            with scope("fedzo.local"):
+                deltas_l, losses_l = local_deltas(b0, params, batches_l,
+                                                  rngs_l)
 
-            if use_air:
-                part, sq_l = kops.aircomp_reduce(deltas_l, maskf_l / m_div,
-                                                 spec.d, block_rows=br)
-                mean = jax.lax.psum(part, axis)
-            elif use_rowcoef:
-                part = jnp.einsum("mn,m->n", deltas_l, maskf_l)
-                mean = jax.lax.psum(part, axis) / m_div
-                sq_l = jnp.zeros((deltas_l.shape[0],), jnp.float32)
-            else:
-                part = jnp.sum(deltas_l, axis=0)
-                mean = jax.lax.psum(part, axis) / M
-                sq_l = jnp.zeros((deltas_l.shape[0],), jnp.float32)
+            with scope("fedzo.aggregate"):
+                if use_air:
+                    part, sq_l = kops.aircomp_reduce(
+                        deltas_l, maskf_l / m_div, spec.d, block_rows=br)
+                    mean = jax.lax.psum(part, axis)
+                elif use_rowcoef:
+                    part = jnp.einsum("mn,m->n", deltas_l, maskf_l)
+                    mean = jax.lax.psum(part, axis) / m_div
+                    sq_l = jnp.zeros((deltas_l.shape[0],), jnp.float32)
+                else:
+                    part = jnp.sum(deltas_l, axis=0)
+                    mean = jax.lax.psum(part, axis) / M
+                    sq_l = jnp.zeros((deltas_l.shape[0],), jnp.float32)
             return mean, sq_l, losses_l
 
         def shard_body_faults(b0, params, batches_l, rngs_l, chan_l, w_l,
@@ -132,23 +140,28 @@ def make_sharded_round(loss_fn, cfg: FedZOConfig, mesh: Mesh, *,
             scrub runs on each device's rows and the divisor is a psum of
             per-shard coefficient sums — mirroring ``mask_stats`` on the
             combined channel ∧ fault mask bit-for-bit on one device."""
-            deltas_l, losses_l = local_deltas(b0, params, batches_l, rngs_l)
-            deltas_l, ok_l = faults.model.scrub(deltas_l, fmask_l, corrupt_l)
-            combined_l = (chan_l & ok_l).astype(jnp.float32)
-            n_sched = jax.lax.psum(jnp.sum(combined_l), axis)
-            coef_l = combined_l * w_l
-            if weights is None:
-                div = jnp.maximum(n_sched, 1.0)
-            else:
-                div = jnp.maximum(jax.lax.psum(jnp.sum(coef_l), axis), 1e-8)
-            if use_air:
-                part, sq_l = kops.aircomp_reduce(deltas_l, coef_l / div,
-                                                 spec.d, block_rows=br)
-                mean = jax.lax.psum(part, axis)
-            else:
-                part = jnp.einsum("mn,m->n", deltas_l, coef_l)
-                mean = jax.lax.psum(part, axis) / div
-                sq_l = jnp.zeros((deltas_l.shape[0],), jnp.float32)
+            with scope("fedzo.local"):
+                deltas_l, losses_l = local_deltas(b0, params, batches_l,
+                                                  rngs_l)
+            with scope("fedzo.aggregate"):
+                deltas_l, ok_l = faults.model.scrub(deltas_l, fmask_l,
+                                                    corrupt_l)
+                combined_l = (chan_l & ok_l).astype(jnp.float32)
+                n_sched = jax.lax.psum(jnp.sum(combined_l), axis)
+                coef_l = combined_l * w_l
+                if weights is None:
+                    div = jnp.maximum(n_sched, 1.0)
+                else:
+                    div = jnp.maximum(jax.lax.psum(jnp.sum(coef_l), axis),
+                                      1e-8)
+                if use_air:
+                    part, sq_l = kops.aircomp_reduce(deltas_l, coef_l / div,
+                                                     spec.d, block_rows=br)
+                    mean = jax.lax.psum(part, axis)
+                else:
+                    part = jnp.einsum("mn,m->n", deltas_l, coef_l)
+                    mean = jax.lax.psum(part, axis) / div
+                    sq_l = jnp.zeros((deltas_l.shape[0],), jnp.float32)
             return mean, sq_l, losses_l, coef_l, div, n_sched
 
         if faults is not None:
@@ -171,34 +184,37 @@ def make_sharded_round(loss_fn, cfg: FedZOConfig, mesh: Mesh, *,
                 check_vma=False)(buf0, server_params, client_batches,
                                  client_rngs, maskf)
 
-        if use_air:
-            # Δ_max / Eq.-17 noise on the replicated mean: literally the
-            # tail of aircomp_aggregate_flat, fed by the psum'd partials
-            sigma_w2 = P_TX / (10.0 ** (cfg.snr_db / 10.0))
-            delta_max = jnp.max(jnp.where(maskf > 0, sq, 0.0))
-            noise_var = sigma_w2 * delta_max / (
-                m_div ** 2 * float(spec.d) * P_TX * cfg.h_min ** 2)
-            noise_std = jnp.sqrt(noise_var)
-            agg_flat = kops.zo_walk(agg_flat, kops.key_words(noise_rng),
-                                    jnp.zeros((2,), jnp.int32),
-                                    jnp.stack([noise_std, jnp.float32(0.0)]),
-                                    kind="normal", block_rows=br)
-            air_stats = {"aircomp_noise_std": noise_std,
-                         "delta_max": delta_max, "m_effective": m_sched}
-        elif mask is not None or faults is not None:
-            air_stats = {"m_effective": m_sched}
-        if faults is not None:
-            air_stats["m_corrupt"] = faults.n_corrupt
+        with scope("fedzo.aggregate"):
+            if use_air:
+                # Δ_max / Eq.-17 noise on the replicated mean: literally the
+                # tail of aircomp_aggregate_flat, fed by the psum'd partials
+                sigma_w2 = P_TX / (10.0 ** (cfg.snr_db / 10.0))
+                delta_max = jnp.max(jnp.where(maskf > 0, sq, 0.0))
+                noise_var = sigma_w2 * delta_max / (
+                    m_div ** 2 * float(spec.d) * P_TX * cfg.h_min ** 2)
+                noise_std = jnp.sqrt(noise_var)
+                agg_flat = kops.zo_walk(
+                    agg_flat, kops.key_words(noise_rng),
+                    jnp.zeros((2,), jnp.int32),
+                    jnp.stack([noise_std, jnp.float32(0.0)]),
+                    kind="normal", block_rows=br)
+                air_stats = {"aircomp_noise_std": noise_std,
+                             "delta_max": delta_max, "m_effective": m_sched}
+            elif mask is not None or faults is not None:
+                air_stats = {"m_effective": m_sched}
+            if faults is not None:
+                air_stats["m_corrupt"] = faults.n_corrupt
 
-        agg = unflatten(agg_flat, spec)
-        if momentum is not None and cfg.server_momentum > 0:
-            momentum = jax.tree.map(
-                lambda m, g: (cfg.server_momentum * m + g).astype(m.dtype),
-                momentum, agg)
-            agg = momentum
-        new_params = tree_add(server_params, agg)
-        metrics = {"mean_local_loss": jnp.mean(losses),
-                   "first_loss": jnp.mean(losses[:, 0]), **air_stats}
+            agg = unflatten(agg_flat, spec)
+            if momentum is not None and cfg.server_momentum > 0:
+                momentum = jax.tree.map(
+                    lambda m, g: (cfg.server_momentum * m
+                                  + g).astype(m.dtype),
+                    momentum, agg)
+                agg = momentum
+            new_params = tree_add(server_params, agg)
+            metrics = {"mean_local_loss": jnp.mean(losses),
+                       "first_loss": jnp.mean(losses[:, 0]), **air_stats}
         if momentum is not None:
             return new_params, metrics, momentum
         return new_params, metrics

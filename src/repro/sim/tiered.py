@@ -609,7 +609,9 @@ def run_tiered_experiment(loss_fn, params, store: HostStore,
             while t < rounds:
                 fut, idx, end, seg_fstate, seg_cstate = pending
                 w0 = time.perf_counter()
-                xs, smeta = fut.result()
+                with (tracer.span("tiered.stage_wait", t0=t)
+                      if tracer is not None else nullcontext()):
+                    xs, smeta = fut.result()
                 waited = time.perf_counter() - w0
                 if cold:
                     cold = False    # nothing to overlap the first wait with

@@ -1,5 +1,5 @@
-"""repro.obs: in-scan taps, trace spans, comms ledger, manifests, bench
-snapshots (DESIGN.md §14).
+"""repro.obs: in-scan taps, trace spans, comms ledger, manifests
+(DESIGN.md §14).
 
 Pins the subsystem's contracts:
 
@@ -9,19 +9,23 @@ Pins the subsystem's contracts:
   rows bitwise-match the final ring via ``history()``.
 - **Spans separate compile from execute**: one ``compile`` span per static
   shape (the checkpointed runner reuses its executable across same-size
-  segments), spans nest with correct depth/parent.
+  segments), spans nest with correct depth/parent, and every span is also
+  a profiler annotation on the trace's host plane.
+- **Named scopes reach the compiled program**: every registered scope is on
+  the ``op_name`` paths of a round's compiled HLO, the loss queries sit
+  inside the local phase, and an unregistered name is refused.
 - **Ledger columns are deterministic in t**: ring-limited and full runs
   annotate identically; the seed-path byte model equals the measured
   ``seedcomm.wire_bytes`` of an actual compressed message.
 - **Manifests cross-check with checkpoints**: the run manifest's
   ``config_hash`` equals the snapshot sidecar's.
-- **Bench snapshots accumulate**: re-saving a suite pushes the previous
-  snapshot into the same file's bounded history.
 """
 from __future__ import annotations
 
+import glob
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -210,6 +214,142 @@ def test_checkpointed_spans_manifest_and_taps(tmp_path):
     assert res.manifest["rounds_done"] == rounds
 
 
+def _host_events(profile_dir, names):
+    """(name, start_ns, end_ns, stats) of the host-plane events with the
+    given names in a profiler trace directory."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(f"{profile_dir}/**/*.xplane.pb", recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                out.extend((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                            dict(e.stats))
+                           for e in line.events if e.name in names)
+    return out
+
+
+def test_checkpointed_spans_on_profiler_clock(tmp_path):
+    """Each segment's host phases are profiler annotations: segment holds
+    segment.dispatch, segment.fetch and checkpoint.save, all carrying the
+    segment's first round as t0."""
+    store, cfg, p0 = _setup(), _cfg(), _params()
+    prof = str(tmp_path / "prof")
+    tr = obs.Tracer(profile_dir=prof)
+    engine.run_experiment(softmax_loss, p0, store, cfg, 8, donate=False,
+                          checkpoint_every=4,
+                          checkpoint_dir=str(tmp_path / "ck"), tracer=tr)
+    kids = ("segment.dispatch", "segment.fetch", "checkpoint.save")
+    evs = _host_events(prof, ("segment",) + kids)
+    segs = sorted(e for e in evs if e[0] == "segment")
+    assert [e[3]["t0"] for e in segs] == [0, 4]
+    for name, s, e, stats in segs:
+        inside = [k for k in evs
+                  if k[0] in kids and k[3]["t0"] == stats["t0"]]
+        assert sorted(k[0] for k in inside) == sorted(kids)
+        assert all(s <= ks and ke <= e for _, ks, ke, _ in inside)
+        order = [k[0] for k in sorted(inside, key=lambda k: k[1])]
+        assert order == list(kids)
+    # the host-clock record holds the same tree
+    for name in kids:
+        assert [tr.spans[s.parent].name for s in tr.named(name)] == \
+            ["segment", "segment"], name
+
+
+def test_tiered_stage_wait_span():
+    """The tiered runner names its wait for a staged cohort."""
+    x, y = make_classification(320, 12, 3, seed=0)
+    host = sim.build_host_store(noniid_shards(x, y, 4), n_buckets=1)
+    tr = obs.Tracer()
+    engine.run_experiment(softmax_loss, _params(), host, _cfg(), 4,
+                          donate=False, stream_segment=2, tracer=tr)
+    waits = tr.named("tiered.stage_wait")
+    assert [s.meta["t0"] for s in waits] == [0, 2]
+    assert [s.meta["t0"] for s in tr.named("tiered_segment")] == [0, 2]
+
+
+# ---------------------------------------------------------------------------
+# named scopes in the compiled program
+
+
+def _scope_paths(text):
+    """The registered scopes on each instruction's op_name path of a
+    compiled program, for the paths that start at the program's root (a
+    reduction's body computation repeats its caller's relative path)."""
+    out = []
+    for path in re.findall(r'op_name="(jit\([^"]*)"', text):
+        parts = set(re.split(r"[/()]", path))
+        out.append({s for s in obs.SCOPES if s in parts})
+    return out
+
+
+def _experiment_text(**kw):
+    from repro.workloads import neural
+    task = neural.make_task("softmax", n_train=240, n_test=64, n_clients=6,
+                            n_features=24, n_classes=4)
+    cfg = neural.default_config(
+        task, n_participating=3, local_iters=2, b1=4, b2=3, lr=1e-2,
+        aircomp=True, channel_model=sim.ChannelModel.from_doppler(0.02),
+        **kw)
+
+    def eval_fn(p):
+        return {"test_loss": task.loss(p, task.test)}
+
+    fn = sim.make_experiment_fn(task.loss, cfg, 2, eval_fn=eval_fn,
+                                eval_every=1)
+    key = jax.random.key(0)
+    cstate = cfg.channel_model.init_state(task.store.n_clients,
+                                          sim.channel.init_key(key))
+    return fn.lower(task.init(0), None, key, None, cstate, None,
+                    task.store).compile().as_text()
+
+
+@pytest.mark.parametrize("plan", [
+    {"flat_params": True, "flat_block_rows": 8},
+    {},
+    {"batch_directions": True, "direction_conv": "block"},
+], ids=["flat", "pytree", "wide"])
+def test_every_scope_in_compiled_experiment(plan):
+    """AirComp over a fading channel with an in-scan eval runs every layer
+    of a round, on each local-phase plan."""
+    paths = _scope_paths(_experiment_text(**plan))
+    seen = set().union(*paths)
+    assert seen == set(obs.SCOPES)
+
+
+def test_query_scope_nested_in_local_phase():
+    paths = _scope_paths(_experiment_text(flat_params=True,
+                                          flat_block_rows=8))
+    assert any("fedzo.query" in p for p in paths)
+    for p in paths:
+        if "fedzo.query" in p:
+            assert "fedzo.local" in p
+        # the top-level layers never nest in one another
+        assert len(p - {"fedzo.query"}) <= 1, p
+
+
+def test_sharded_round_scopes():
+    store, p0 = _setup(), _params()
+    cfg = _cfg(flat_params=True, flat_block_rows=8, aircomp=True,
+               snr_db=10.0)
+    rf = sim.make_sharded_round(softmax_loss, cfg, sim.make_clients_mesh())
+    batches = sim.sample_batches(store, jnp.arange(2), jax.random.key(7),
+                                 cfg.local_iters, cfg.b1)
+    rngs = jax.random.split(jax.random.key(1), 2)
+    text = jax.jit(lambda p, b, r, c: rf(
+        softmax_loss, p, b, r, cfg, channel_rng=c)).lower(
+            p0, batches, rngs, jax.random.key(2)).compile().as_text()
+    seen = set().union(*_scope_paths(text))
+    assert seen == {"fedzo.local", "fedzo.query", "fedzo.aggregate"}
+
+
+def test_scope_refuses_unregistered_name():
+    with pytest.raises(ValueError, match="unregistered scope"):
+        obs.scope("fedzo.server")
+    with obs.scope("fedzo.local"):
+        pass
+
+
 # ---------------------------------------------------------------------------
 # comms ledger
 
@@ -300,44 +440,6 @@ def test_sweep_tracer_one_compile_per_static_group():
     # 2 static groups (local_iters) × vmapped lr axis
     assert len(tr.named("compile")) == 2
     assert len(tr.named("execute")) == 2
-
-
-# ---------------------------------------------------------------------------
-# kernel timing harness
-
-
-def test_kernel_report_measures_and_models():
-    reps = obs.kernel_report(n=1024, b2=4, m=4)
-    names = [kt.name for kt in reps]
-    assert any("zo_walk" in n for n in names)
-    assert any("zo_replay" in n for n in names)
-    assert any("aircomp_reduce" in n for n in names)
-    for kt in reps:
-        assert kt.measured_us > 0
-        assert kt.model_us > 0
-        assert kt.hbm_passes >= 2.0
-        rows = kt.rows()
-        assert rows[0][0].endswith("_us")
-        assert rows[1][0].endswith("_hbm_model_us")
-
-
-# ---------------------------------------------------------------------------
-# bench snapshots
-
-
-def test_bench_snapshot_accumulates_history(tmp_path):
-    d = str(tmp_path)
-    rows1 = [("suitex/a_us", 10.0, 1), ("suitex/b_us", 20.0, 2)]
-    rows2 = [("suitex/a_us", 11.0, 1), ("suitex/b_us", 19.0, 2)]
-    p = obs.save_bench("suitex", rows1, out_dir=d, config={"note": "r1"})
-    assert os.path.basename(p) == "BENCH_suitex.json"
-    obs.save_bench("suitex", rows2, out_dir=d)
-    snap = obs.load_benches(d)["suitex"]
-    assert [r["us_per_call"] for r in snap["rows"]] == [11.0, 19.0]
-    assert len(snap["history"]) == 1
-    assert [r["us_per_call"] for r in snap["history"][0]["rows"]] == \
-        [10.0, 20.0]
-    assert snap["jax_version"] == jax.__version__
 
 
 def test_manifest_roundtrip(tmp_path):

@@ -133,3 +133,50 @@ def test_flat_experiment_compiles_with_kernels(one_chip, monkeypatch):
     text = fn.lower(params, None, key, None, None, None,
                     sds(task.store)).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def test_scopes_and_kernel_names_in_compiled_program(one_chip, monkeypatch):
+    """The names a chip trace is read by survive the chip's compiler: the
+    program's registered scopes on the instructions' ``op_name`` paths,
+    and the kernel instruction names the trace reducer keys on. The flat
+    AirComp experiment with a fading channel and an in-scan eval runs
+    every registered scope and all four kernels."""
+    import re
+
+    from repro import sim
+    from repro.kernels import ops
+    from repro.obs.trace import SCOPES
+    from repro.workloads import neural
+
+    monkeypatch.setattr(ops, "_auto_interpret",
+                        lambda i: False if i is None else i)
+    task = neural.make_task("softmax", n_train=6000, n_test=1000,
+                            n_clients=50, partition="shards")
+    cfg = neural.default_config(
+        task, n_participating=10, lr=1e-3, flat_params=True, aircomp=True,
+        channel_model=sim.ChannelModel.from_doppler(0.02))
+
+    def eval_fn(p):
+        return {"test_loss": task.loss(p, task.test)}
+
+    fn = sim.make_experiment_fn(task.loss, cfg, 2, eval_fn=eval_fn,
+                                eval_every=1)
+
+    def sds(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = sds(jax.eval_shape(lambda: task.init(0)))
+    key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                               sharding=one_chip)
+    cstate = sds(jax.eval_shape(
+        lambda: cfg.channel_model.init_state(50, jax.random.key(0))))
+    text = fn.lower(params, None, key, None, cstate, None,
+                    sds(task.store)).compile().as_text()
+    for name in SCOPES:
+        assert f"{name}/" in text or f"({name})" in text, name
+    instrs = set(re.findall(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = ", text,
+                            re.MULTILINE))
+    bases = {re.sub(r"\.\d+$", "", n) for n in instrs}
+    for kernel in ("zo_walk", "zo_replay", "zo_dirnorms", "aircomp_reduce"):
+        assert kernel in bases, kernel
