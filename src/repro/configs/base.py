@@ -162,7 +162,9 @@ class FedZOConfig:
     # rbg/unsafe_rbg trade threefry's splittability guarantees for ~2-4x
     # faster in-scan direction generation (simulation-scale only).
     prng_impl: str = "threefry2x32"
-    flat_block_rows: int = 0   # kernel grid rows per block; 0 = default (512)
+    # kernel grid rows per block; 0 = sized from d (blocks of at most 512
+    # rows, utils/flatparams.flat_geometry)
+    flat_block_rows: int = 0
     server_momentum: float = 0.0  # FedOpt-style momentum on aggregated deltas
     seed: int = 0
     # AirComp (Section IV); snr_db=None disables the channel simulation

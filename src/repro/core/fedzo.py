@@ -61,16 +61,28 @@ def _wide_setup(params, cfg: FedZOConfig):
     """Flat geometry for the batched-direction (wide) path.
 
     The wide phase never enters a Pallas kernel, so it pads only to the
-    vector-lane width — NOT to the kernel block (BLOCK_ROWS·LANES can be
-    8× the model size at softmax-regression scale, and every [b2, n_pad]
-    direction block would pay for the dead columns). The kernel geometry
-    is kept only when the fused AirComp kernel consumes the delta matrix.
+    vector-lane width — NOT to the kernel block, whose extra rows every
+    [b2, n_pad] direction block would pay for. The kernel geometry is kept
+    only when the fused AirComp kernel consumes the delta matrix.
     """
     from repro.kernels.zo_axpy import LANES
 
     if cfg.aircomp:
         return _flat_setup(params, cfg)
-    return flat_spec(params, block=LANES), (cfg.flat_block_rows or None)
+    return flat_spec(params, block=LANES), None
+
+
+def flat_layout(params, cfg: FedZOConfig):
+    """The flat buffer a run's local phase uses, for its manifest:
+    ``{"d", "n_pad", "block_rows"}`` (block_rows None where no kernel
+    runs), or None on the pytree path. n_pad / d is the pad ratio."""
+    if cfg.batch_directions:
+        spec, br = _wide_setup(params, cfg)
+    elif cfg.flat_params:
+        spec, br = _flat_setup(params, cfg)
+    else:
+        return None
+    return {"d": spec.d, "n_pad": spec.n_pad, "block_rows": br}
 
 
 def flat_local_iterate(loss_fn, buf, spec, batch, rng, cfg: FedZOConfig,

@@ -20,7 +20,7 @@ and the noise itself is injected with a single ``zo_walk`` pass over the
 d-sized mean (noise generated in-kernel from the counter convention) — the
 M×d matrix is never touched again.
 
-VMEM budget: the block is [M, block_rows, 128] fp32 — at the default 512
+VMEM budget: the block is [M, block_rows, 128] fp32 — at the largest, 512
 block rows that is M·256 KiB, double-buffered M·512 KiB: 25 MiB at the
 paper's M=50, past the 16 MiB of scoped VMEM a TPU kernel gets by default.
 The kernel therefore asks for the double-buffered footprint plus 8 MiB of

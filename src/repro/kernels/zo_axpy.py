@@ -10,9 +10,14 @@ times per estimator sample:
   update         x ← x − η·Σ_n c_n v_n       (replayed from seeds)
 
 These are pure HBM-bandwidth ops; the kernel's job is fusion (XLA will not
-fuse across the loss-forward boundary) and explicit VMEM tiling. Block size
-is 8·128·64 = 64Ki elements → 256 KiB fp32 per stream, 3 streams ≈ 768 KiB of
-the ~16 MiB VMEM budget, leaving room for double buffering.
+fuse across the loss-forward boundary) and explicit VMEM tiling. The largest
+block is 8·128·64 = 64Ki elements (512 rows of 128 lanes) → 256 KiB fp32 per
+stream, 3 streams ≈ 768 KiB of the ~16 MiB VMEM budget, leaving room for
+double buffering. The flat engine sizes its block to the model
+(``utils/flatparams.flat_geometry``): the fewest grid steps of at most 512
+rows, so the pad region the kernels generate directions for is under one
+sublane tile per step; the 512-row ``BLOCK_ROWS`` is the kernels' default
+when called directly.
 
 Inputs are the flattened 1-D parameter leaf (padded to a block multiple by
 ops.py). ``zo_axpy2(x, u, v, a, b) = x + a·u + b·v`` is the general form;
@@ -58,9 +63,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-BLOCK = 8 * 128 * 64  # 64Ki elements per grid step
+BLOCK = 8 * 128 * 64  # 64Ki elements: the largest grid step
 LANES = 128
-BLOCK_ROWS = BLOCK // LANES  # 512 rows of 128 lanes per grid step
+BLOCK_ROWS = BLOCK // LANES  # 512 rows of 128 lanes: the largest block
 # Scalar operands ride in SMEM as [k, 1] columns: the whole column is one
 # block, and under vmap the batch axis lands in front of the two trailing
 # dims, so the batched block still spans them as the TPU compiler requires.

@@ -5,9 +5,9 @@ found on disk months later: the config and its hash (the SAME
 ``checkpoint.config_hash`` the snapshot sidecars record, so a manifest and
 a checkpoint from one run cross-check), the strategy name, the jax/python
 versions, the git sha of the working tree, the device/mesh topology, the
-communication ledger, the fault-model and wireless-scenario
-configurations, and the structured event stream (divergence rollbacks)
-the run produced.
+communication ledger, the flat buffer's kernel geometry (``d``, ``n_pad``,
+``block_rows``), the fault-model and wireless-scenario configurations, and
+the structured event stream (divergence rollbacks) the run produced.
 
 ``sim.run_experiment`` emits one alongside durable checkpoints
 (``<checkpoint_dir>/manifest.json``) and next to a file-backed metric sink
@@ -54,6 +54,7 @@ def build_manifest(cfg=None, *, strategy: Optional[str] = None,
                    rounds: Optional[int] = None,
                    n_clients: Optional[int] = None, ledger=None,
                    faults=None, channel=None, events=None, mesh=None,
+                   flat: Optional[dict] = None,
                    extra: Optional[dict] = None) -> dict:
     """Assemble a run manifest dict. Everything is optional so partial
     emitters (benchmarks) reuse the same provenance block."""
@@ -84,6 +85,8 @@ def build_manifest(cfg=None, *, strategy: Optional[str] = None,
     if mesh is not None:
         md["mesh"] = {"axes": dict(zip(mesh.axis_names, mesh.devices.shape)),
                       "devices": [str(d) for d in mesh.devices.ravel()]}
+    if flat is not None:
+        md["flat_geometry"] = flat
     md["events"] = [dict(e) for e in (events or [])]
     if extra:
         md.update(extra)

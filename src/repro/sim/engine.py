@@ -69,6 +69,7 @@ from jax.experimental import io_callback
 from repro.configs.base import FedZOConfig
 from repro.core import aircomp
 from repro.core import strategy as strategy_mod
+from repro.core.fedzo import flat_layout
 from repro.core.strategy import _static_positive  # noqa: F401  (re-export)
 from repro.obs import manifest as obs_manifest
 from repro.obs.ledger import CommsLedger
@@ -569,6 +570,7 @@ def run_experiment(loss_fn, params, store: ClientStore, cfg: FedZOConfig,
     # the byte model reads params metadata, so build it BEFORE the run
     # donates the buffers
     ledger = CommsLedger.from_run(cfg, params, channel=channel)
+    flat = flat_layout(params, cfg)
     n_clients = store.n_clients
     if checkpoint_every > 0:
         return _run_checkpointed(
@@ -580,7 +582,7 @@ def run_experiment(loss_fn, params, store: ClientStore, cfg: FedZOConfig,
             checkpoint_dir=checkpoint_dir, resume=resume,
             max_segments=max_segments, segment_callback=segment_callback,
             max_retries=max_retries, lr_backoff=lr_backoff, tap=tap,
-            tracer=tracer, ledger=ledger)
+            tracer=tracer, ledger=ledger, flat=flat)
     fn = make_experiment_fn(loss_fn, cfg, rounds, strategy=strat,
                             eval_fn=eval_fn, eval_every=eval_every,
                             ring_size=ring_size, round_fn=round_fn,
@@ -611,7 +613,7 @@ def run_experiment(loss_fn, params, store: ClientStore, cfg: FedZOConfig,
         result.manifest = obs_manifest.build_manifest(
             cfg, strategy=strat.name, rounds=rounds, n_clients=n_clients,
             ledger=ledger, faults=faults, channel=channel,
-            events=result.events,
+            events=result.events, flat=flat,
             extra={"tap_every": tap.every} if tap is not None else None)
         obs_manifest.write_manifest(f"{sink_path}.manifest.json",
                                     result.manifest)
@@ -673,7 +675,7 @@ def _run_checkpointed(loss_fn, params, store, cfg, rounds, *, strategy,
                       checkpoint_every, checkpoint_dir, resume,
                       max_segments, segment_callback, max_retries,
                       lr_backoff, tap=None, tracer=None,
-                      ledger=None) -> ExperimentResult:
+                      ledger=None, flat=None) -> ExperimentResult:
     """The durable segment loop behind ``run_experiment(...,
     checkpoint_every=k)``. Invariants:
 
@@ -738,7 +740,7 @@ def _run_checkpointed(loss_fn, params, store, cfg, rounds, *, strategy,
         man = obs_manifest.build_manifest(
             cfg, strategy=strat.name, rounds=rounds,
             n_clients=store.n_clients, ledger=ledger, faults=faults,
-            channel=cfg.channel_model, events=events,
+            channel=cfg.channel_model, events=events, flat=flat,
             extra={"checkpoint_every": checkpoint_every, "lr": cur_lr,
                    "rounds_done": t,
                    "tap_every": tap.every if tap is not None else None})

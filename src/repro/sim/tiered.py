@@ -60,6 +60,7 @@ import numpy as np
 
 from repro.configs.base import FedZOConfig
 from repro.core import strategy as strategy_mod
+from repro.core.fedzo import flat_layout
 from repro.obs import manifest as obs_manifest
 from repro.obs.ledger import CommsLedger
 from repro.obs.taps import RoundTap
@@ -454,6 +455,7 @@ def run_tiered_experiment(loss_fn, params, store: HostStore,
         tap = RoundTap(sink, tap_every)
     channel = cfg.channel_model
     ledger = CommsLedger.from_run(cfg, params, channel=channel)
+    flat = flat_layout(params, cfg)
     if checkpoint_every > 0 and checkpoint_dir is None:
         raise ValueError("checkpoint_every > 0 requires checkpoint_dir")
 
@@ -540,6 +542,7 @@ def run_tiered_experiment(loss_fn, params, store: HostStore,
         man = obs_manifest.build_manifest(
             cfg, strategy=strat.name, rounds=rounds, n_clients=n_clients,
             ledger=ledger, faults=faults, channel=channel, events=events,
+            flat=flat,
             extra={"checkpoint_every": checkpoint_every, "lr": cur_lr,
                    "rounds_done": t,
                    "tap_every": tap.every if tap is not None else None,
@@ -721,7 +724,7 @@ def run_tiered_experiment(loss_fn, params, store: HostStore,
         result.manifest = obs_manifest.build_manifest(
             cfg, strategy=strat.name, rounds=rounds, n_clients=n_clients,
             ledger=ledger, faults=faults, channel=channel,
-            events=result.events,
+            events=result.events, flat=flat,
             extra={**({"tap_every": tap.every} if tap is not None else {}),
                    **tiered_block()})
         obs_manifest.write_manifest(f"{sink_path}.manifest.json",

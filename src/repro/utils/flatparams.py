@@ -10,6 +10,8 @@ everything needed to flatten once and then unflatten *views* for free:
                                 dtypes, offsets, padded length)
 - ``flatten(params, spec)``   → fp32 [n_pad] buffer, zero-padded to a
                                 kernel-block multiple
+- ``flat_geometry(params, br)`` → (spec, block_rows) the kernels run on;
+                                ``br=0`` sizes the block from d
 - ``unflatten(buf, spec)``    → pytree of reshaped slices cast back to the
                                 original leaf dtypes (XLA slices of the
                                 buffer — no copy until a consumer forces
@@ -22,6 +24,7 @@ kernels and from the pytree reference path (DESIGN.md §7).
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Tuple
 
@@ -95,13 +98,33 @@ def unflatten(buf, spec: FlatSpec):
     return jax.tree.unflatten(spec.treedef, out)
 
 
-def flat_geometry(params, block_rows: int = 0):
-    """(spec, block_rows kwarg) for a given kernel-block-rows setting.
+def _sized_block_rows(d: int, buf_dtype="float32") -> int:
+    """Kernel block rows sized to d: the fewest grid steps of at most
+    ``BLOCK_ROWS`` rows that cover ceil(d/128) rows, each step's rows
+    rounded up to the buffer dtype's sublane tile (8 rows of 32-bit, 16 of
+    16-bit). The pad region is then under one tile per grid step — at
+    d = 7,850 one block of 64 rows (n_pad 8,192) instead of 512 (65,536).
+    """
+    tile = 8 * max(1, 4 // jnp.dtype(buf_dtype).itemsize)
+    rows = max(1, -(-d // LANES))
+    steps = -(-rows // BLOCK_ROWS)
+    per = -(-rows // steps)
+    return -(-per // tile) * tile
+
+
+def flat_geometry(params, block_rows: int = 0, *, buf_dtype="float32"):
+    """(spec, block_rows) for a given kernel-block-rows setting.
 
     THE one mapping from a block-rows config to flat-buffer geometry. The
     perturb end (fedzo) and the replay end (seedcomm) must derive identical
     geometry for counter-convention seed replay to be bit-exact — both call
-    this. block_rows=0 means the kernel default.
+    this. block_rows=0 sizes the block from d (``_sized_block_rows``); the
+    rows returned are always explicit, so every kernel of a run (walk,
+    replay, dirnorms, the AirComp reduce and noise walk) sees one geometry
+    and none re-pads to its own default block.
     """
-    spec = flat_spec(params, block=block_rows * LANES if block_rows else 0)
-    return spec, (block_rows or None)
+    if not block_rows:
+        d = sum(math.prod(l.shape) for l in jax.tree.leaves(params))
+        block_rows = _sized_block_rows(d, buf_dtype)
+    spec = flat_spec(params, block=block_rows * LANES, buf_dtype=buf_dtype)
+    return spec, block_rows

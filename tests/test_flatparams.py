@@ -1,10 +1,12 @@
-"""FlatParams: flatten/unflatten round-trips, padding, spec caching."""
+"""FlatParams: flatten/unflatten round-trips, padding, spec caching, and
+the kernel geometry ``flat_geometry`` sizes from d."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.utils.flatparams import flat_spec, flatten, unflatten
+from repro.kernels.zo_axpy import BLOCK_ROWS, LANES
+from repro.utils.flatparams import flat_geometry, flat_spec, flatten, unflatten
 from repro.utils.tree import tree_size
 
 
@@ -89,3 +91,48 @@ def test_flatten_inside_jit():
     for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(tree)):
         np.testing.assert_array_equal(np.asarray(a, np.float32),
                                       np.asarray(b, np.float32))
+
+
+# -- kernel geometry sized from d -------------------------------------------
+
+
+def _params_of(d):
+    """A parameter pytree of d scalars, as shapes only."""
+    half = d // 2
+    return {"a": jax.ShapeDtypeStruct((half,), jnp.float32),
+            "b": jax.ShapeDtypeStruct((d - half,), jnp.float32)}
+
+
+@pytest.mark.parametrize("buf_dtype,tile", [("float32", 8), ("bfloat16", 16)])
+@pytest.mark.parametrize("d", [900, 7850, 50090, 65536, 65537, 1663370])
+def test_derived_geometry_pads_under_a_tile_per_step(d, buf_dtype, tile):
+    """block_rows=0: blocks of at most 512 rows, a whole number of sublane
+    tiles, and under one tile of padding per grid step."""
+    spec, br = flat_geometry(_params_of(d), 0, buf_dtype=buf_dtype)
+    assert spec.d == d and spec.buf_dtype == buf_dtype
+    assert 0 < br <= BLOCK_ROWS and br % tile == 0
+    per = br * LANES
+    assert spec.n_pad % per == 0 and spec.n_pad >= d
+    steps = spec.n_pad // per
+    assert spec.n_pad - d < steps * tile * LANES
+    # never more grid steps than the 512-row block needs
+    assert steps == -(-d // (BLOCK_ROWS * LANES))
+
+
+@pytest.mark.parametrize("d,rows,n_pad", [(7850, 64, 8192),
+                                          (50090, 392, 50176),
+                                          (1663370, 504, 1677312)])
+def test_derived_geometry_of_the_benchmark_models(d, rows, n_pad):
+    spec, br = flat_geometry(_params_of(d), 0)
+    assert (br, spec.n_pad) == (rows, n_pad)
+
+
+def test_explicit_block_rows_geometry_unchanged():
+    """An explicit block_rows keeps its meaning: pad to its block, as
+    flat_spec does."""
+    tree = _mixed_tree()
+    spec, br = flat_geometry(tree, 4)
+    assert br == 4 and spec is flat_spec(tree, block=4 * LANES)
+    assert spec.n_pad % 512 == 0 and 0 <= spec.n_pad - spec.d < 512
+    spec, br = flat_geometry(_params_of(7850), 512)
+    assert (br, spec.n_pad) == (512, 65536)

@@ -442,6 +442,27 @@ def test_sweep_tracer_one_compile_per_static_group():
     assert len(tr.named("execute")) == 2
 
 
+@pytest.mark.parametrize("plan,want", [
+    ({"flat_params": True}, {"d": 39, "n_pad": 1024, "block_rows": 8}),
+    ({"flat_params": True, "flat_block_rows": 16},
+     {"d": 39, "n_pad": 2048, "block_rows": 16}),
+    ({"batch_directions": True, "direction_conv": "block"},
+     {"d": 39, "n_pad": 128, "block_rows": None}),
+    ({}, None),
+], ids=["flat_derived", "flat_explicit", "wide", "pytree"])
+def test_manifest_records_flat_geometry(tmp_path, plan, want):
+    """The manifest says which flat buffer the kernels ran on: d, n_pad
+    and block_rows (the pad ratio is n_pad / d); no block on the wide path
+    without AirComp, which runs no kernel; nothing on the pytree path."""
+    path = os.path.join(tmp_path, "rows.jsonl")
+    with obs.JsonlSink(path) as sink:
+        res = engine.run_experiment(softmax_loss, _params(), _setup(),
+                                    _cfg(**plan), 1, sink=sink)
+    man = obs.read_manifest(f"{path}.manifest.json")
+    assert man.get("flat_geometry") == want
+    assert res.manifest.get("flat_geometry") == want
+
+
 def test_manifest_roundtrip(tmp_path):
     cfg = _cfg()
     led = obs.CommsLedger.from_run(cfg, _params())

@@ -180,3 +180,43 @@ def test_scopes_and_kernel_names_in_compiled_program(one_chip, monkeypatch):
     bases = {re.sub(r"\.\d+$", "", n) for n in instrs}
     for kernel in ("zo_walk", "zo_replay", "zo_dirnorms", "aircomp_reduce"):
         assert kernel in bases, kernel
+
+
+def test_flat_experiment_kernels_sized_to_d(one_chip, monkeypatch):
+    """With flat_block_rows=0 every kernel of the flat AirComp experiment
+    at the softmax model's d = 7,850 runs on the geometry sized from d:
+    [64, 128] blocks (n_pad 8,192), and no kernel operand keeps the
+    512-row, 65,536-element block."""
+    import re
+
+    from repro import sim
+    from repro.kernels import ops
+    from repro.workloads import neural
+
+    monkeypatch.setattr(ops, "_auto_interpret",
+                        lambda i: False if i is None else i)
+    task = neural.make_task("softmax", n_train=6000, n_test=1000,
+                            n_clients=50, partition="shards")
+    cfg = neural.default_config(task, n_participating=10, lr=1e-3,
+                                flat_params=True, flat_block_rows=0,
+                                aircomp=True)
+    fn = sim.make_experiment_fn(task.loss, cfg, 2)
+
+    def sds(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = sds(jax.eval_shape(lambda: task.init(0)))
+    key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                               sharding=one_chip)
+    text = fn.lower(params, None, key, None, None, None,
+                    sds(task.store)).compile().as_text()
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    names = {re.sub(r"\.\d+$", "", re.search(r"%([\w.\-]+) = ", l)[1])
+             for l in calls}
+    assert names == {"zo_walk", "zo_replay", "zo_dirnorms",
+                     "aircomp_reduce"}, names
+    blocked = [rows for l in calls
+               for rows in re.findall(r"f32\[(?:\d+,)*?(\d+),128\]", l)]
+    assert blocked and set(blocked) == {"64"}, blocked
+    assert not any("65536" in l or "512,128" in l for l in calls)

@@ -245,3 +245,80 @@ def test_kernels_get_two_key_words_under_every_prng_impl(impl):
                                   np.asarray(words))
     batched = jax.vmap(ops.key_words)(jax.random.split(key, 3))
     assert batched.shape == (3, 2)
+
+
+# -- 4. the derived kernel geometry changes no number -----------------------
+
+D_SOFTMAX = 7850            # the paper's softmax regression, 784·10 + 10
+
+
+def test_derived_geometry_is_one_64_row_block_at_softmax_d():
+    spec, br = fedzo._flat_setup(softmax_init(None),
+                                 FedZOConfig(flat_params=True))
+    assert (spec.d, br, spec.n_pad) == (D_SOFTMAX, 64, 8192)
+
+
+@pytest.mark.parametrize("kernel", ["zo_walk", "zo_replay", "zo_dirnorms"])
+def test_kernels_bit_equal_on_d_under_derived_geometry(kernel):
+    """The counter convention keys each direction element on its global
+    flat index, so v_n[:d] does not depend on the block shape: the derived
+    64-row geometry gives the 512-row geometry's numbers, bit for bit."""
+    d, b2 = D_SOFTMAX, 20
+    x = jax.random.normal(jax.random.key(0), (d,), jnp.float32)
+
+    def run(rows):
+        if kernel == "zo_walk":
+            return ops.zo_walk(x, KEY2, [3, 4], [-0.25, 0.125],
+                               block_rows=rows)
+        if kernel == "zo_replay":
+            coeffs = jax.random.normal(jax.random.key(1), (b2,))
+            return ops.zo_replay(x, KEY2, coeffs, block_rows=rows)
+        return ops.zo_dirnorms(KEY2, d, b2=b2, block_rows=rows)
+
+    np.testing.assert_array_equal(np.asarray(run(64))[:d],
+                                  np.asarray(run(512))[:d])
+
+
+def _softmax_run(flat_block_rows, **kw):
+    from repro import sim
+    from repro.data.synthetic import noniid_shards
+
+    x, y = make_classification(160, 784, 10, seed=0)
+    store = sim.build_store(noniid_shards(x, y, 4))
+    cfg = FedZOConfig(n_devices=4, n_participating=2, local_iters=1, b1=8,
+                      b2=2, lr=1e-3, mu=1e-3, seed=5, flat_params=True,
+                      flat_block_rows=flat_block_rows, **kw)
+    return sim.run_experiment(softmax_loss, softmax_init(None), store, cfg,
+                              2)
+
+
+@pytest.mark.parametrize("kw", [{}, {"aircomp": True, "snr_db": 0.0}],
+                         ids=["mean", "aircomp"])
+def test_run_experiment_same_under_derived_and_512_row_geometry(kw):
+    """A flat run with flat_block_rows=0 (64 rows, n_pad 8,192) walks the
+    512-row run's trajectory (n_pad 65,536): same losses, same params."""
+    derived, wide = _softmax_run(0, **kw), _softmax_run(512, **kw)
+    for name in derived.metrics:
+        np.testing.assert_array_equal(np.asarray(derived.metrics[name]),
+                                      np.asarray(wide.metrics[name]),
+                                      err_msg=name)
+    for a, b in zip(jax.tree.leaves(derived.params),
+                    jax.tree.leaves(wide.params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_seedcomm_round_trip_under_derived_geometry():
+    """Perturb end and replay end both derive the geometry from d
+    (flat_block_rows=0): the replayed delta is the client's."""
+    cfg = FedZOConfig(local_iters=2, lr=1e-3, mu=1e-3, b2=3,
+                      flat_params=True)
+    params = softmax_init(None)
+    x, y = make_classification(32, 784, 10, seed=1)
+    batches = {"x": jnp.asarray(x).reshape(2, 16, 784),
+               "y": jnp.asarray(y).reshape(2, 16)}
+    rng = jax.random.key(42)
+    delta, res = fedzo.client_delta(softmax_loss, params, batches, rng, cfg)
+    recon = seedcomm.reconstruct_delta(seedcomm.compress(rng, res.coeffs,
+                                                         cfg), params, cfg)
+    for a, b in zip(jax.tree.leaves(delta), jax.tree.leaves(recon)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-7)
