@@ -110,13 +110,16 @@ def counter_direction(rng, n, params, kind, dtype=jnp.float32):
 
 def query(loss_fn, buf, spec: FlatSpec, batch):
     """One loss query of a flat buffer: unflatten plus the model's forward,
-    under the ``fedzo.query`` scope."""
+    under the ``fedzo.query`` scope, the forward alone under
+    ``fedzo.forward`` inside it."""
     with scope("fedzo.query"):
-        return loss_fn(unflatten(buf, spec), batch)
+        params = unflatten(buf, spec)
+        with scope("fedzo.forward"):
+            return loss_fn(params, batch)
 
 
 def _query_tree(loss_fn, params, batch):
-    with scope("fedzo.query"):
+    with scope("fedzo.query"), scope("fedzo.forward"):
         return loss_fn(params, batch)
 
 

@@ -1,9 +1,11 @@
 """Models for the paper's own experiments (Sec. V).
 
 - ``softmax_regression``: the Fashion-MNIST multinomial classifier of Sec V-B.
-- ``smallcnn_*``: a trainable LeNet-style SmallCNN — the Sec V-B CNN track
-  (conv → pool → conv → pool → linear head), a first-class FedZO *workload*
-  via ``repro.workloads.neural``.
+- ``fedavg_cnn_*``: FedAvg's MNIST CNN as published (McMahan et al.,
+  arXiv:1602.05629, Sec. 3), the standard CNN of the Sec V-B image track:
+  d = 1,663,370.
+- ``smallcnn_*``: a small LeNet-style CNN (3×3 convs, a linear head) of no
+  published source, kept for the tests' small conv track.
 - ``cnn_*``: a small conv classifier standing in for the pretrained
   CIFAR-10 network of Carlini & Wagner used in Sec V-A (the container is
   offline; we train this surrogate in-repo on synthetic CIFAR-like data).
@@ -48,17 +50,65 @@ def softmax_accuracy(params, batch):
     return jnp.mean((pred == batch["y"]).astype(jnp.float32))
 
 
-# ---------------------------------------------------------------------------
-# trainable LeNet-style SmallCNN (Sec V-B CNN track)
-
-
-def _conv_pool(h, w):
+def _conv_pool(h, w, b=None):
+    """'SAME' conv (plus bias), ReLU, 2×2/2 max pool."""
     h = jax.lax.conv_general_dilated(h, w, (1, 1), "SAME",
                                      dimension_numbers=("NHWC", "HWIO",
                                                         "NHWC"))
+    if b is not None:
+        h = h + b
     h = jax.nn.relu(h)
     return jax.lax.reduce_window(h, -jnp.inf, jax.lax.max, (1, 2, 2, 1),
                                  (1, 2, 2, 1), "VALID")
+
+
+# ---------------------------------------------------------------------------
+# FedAvg's MNIST CNN (McMahan et al., arXiv:1602.05629, Sec. 3)
+
+
+def fedavg_cnn_init(rng, image_shape=(28, 28, 1), n_classes=10):
+    """Two 5×5 'SAME' convs with bias (32 and 64 channels), each followed
+    by ReLU and a 2×2/2 max pool, then FC-512 with ReLU and FC to the
+    classes: 1,663,370 parameters at 28×28×1 and 10 classes. The paper
+    gives no initialisation: weights are He-normal by fan-in, biases 0."""
+    h, w, cin = image_shape
+    flat = (h // 4) * (w // 4) * 64
+    ks = jax.random.split(rng, 4)
+
+    def he(k, shape, fan_in):
+        return jax.random.normal(k, shape, jnp.float32) * (2.0 / fan_in) ** 0.5
+
+    return {"conv1_w": he(ks[0], (5, 5, cin, 32), 25 * cin),
+            "conv1_b": jnp.zeros((32,), jnp.float32),
+            "conv2_w": he(ks[1], (5, 5, 32, 64), 25 * 32),
+            "conv2_b": jnp.zeros((64,), jnp.float32),
+            "fc1_w": he(ks[2], (flat, 512), flat),
+            "fc1_b": jnp.zeros((512,), jnp.float32),
+            "fc2_w": he(ks[3], (512, n_classes), 512),
+            "fc2_b": jnp.zeros((n_classes,), jnp.float32)}
+
+
+def fedavg_cnn_logits(params, images):
+    """images [B, H, W, C] NHWC, pixels as given -> logits [B, n_classes]."""
+    h = _conv_pool(images, params["conv1_w"], params["conv1_b"])
+    h = _conv_pool(h, params["conv2_w"], params["conv2_b"])
+    h = jax.nn.relu(h.reshape(h.shape[0], -1) @ params["fc1_w"]
+                    + params["fc1_b"])
+    return h @ params["fc2_w"] + params["fc2_b"]
+
+
+def fedavg_cnn_loss(params, batch):
+    """batch: {"x": [B, H, W, C], "y": [B]} -> mean cross-entropy."""
+    return mean_xent(fedavg_cnn_logits(params, batch["x"]), batch["y"])
+
+
+def fedavg_cnn_accuracy(params, batch):
+    pred = jnp.argmax(fedavg_cnn_logits(params, batch["x"]), axis=-1)
+    return jnp.mean((pred == batch["y"]).astype(jnp.float32))
+
+
+# ---------------------------------------------------------------------------
+# trainable LeNet-style SmallCNN (tests' small conv track)
 
 
 def smallcnn_init(rng, image_shape=(28, 28, 1), n_classes=10, width=8):

@@ -45,12 +45,13 @@ import jax
 # - fedzo.local: the M vmapped local phases, the ZO kernels included;
 # - fedzo.query: one loss query (unflatten + the model's forward), nested
 #   in fedzo.local;
+# - fedzo.forward: the model's forward alone, nested in fedzo.query;
 # - fedzo.aggregate: client deltas to new params (delta correction, fault
 #   scrub, the mean or AirComp with its noise, the mesh psum, momentum);
 # - fedzo.eval: the in-scan eval.
 # Ops under none of them are the scan's own bookkeeping.
-SCOPES = ("fedzo.cohort", "fedzo.local", "fedzo.query", "fedzo.aggregate",
-          "fedzo.eval")
+SCOPES = ("fedzo.cohort", "fedzo.local", "fedzo.query", "fedzo.forward",
+          "fedzo.aggregate", "fedzo.eval")
 
 
 def scope(name: str):

@@ -13,9 +13,12 @@ perturbed forwards per client, aggregation (plain / size-weighted / AirComp
 / channel-truncated / clients-mesh sharded), and the in-scan top-1 accuracy
 eval — executes as ONE compiled program via ``sim.run_experiment``.
 
-Three registered tracks (``make_task(name)``):
+Four registered tracks (``make_task(name)``):
 
 - ``softmax``     — the Sec. V-B multinomial classifier (models/simple).
+- ``fedavg_cnn``  — FedAvg's MNIST CNN as published (models/simple,
+  d = 1,663,370), with its own lr and μ as the track's defaults
+  (``default_config``).
 - ``cnn``         — the trainable LeNet-style SmallCNN (models/simple).
 - ``transformer`` — a tiny patch-token transformer head built from the
   LM stack's blocks (models/transformer.init_classifier).
@@ -66,6 +69,13 @@ def _cnn_triple(n_features, n_classes, kw):
             simple.smallcnn_loss, simple.smallcnn_accuracy, shape)
 
 
+def _fedavg_cnn_triple(n_features, n_classes, kw):
+    shape = kw.pop("image_shape")
+    return (lambda seed: simple.fedavg_cnn_init(jax.random.key(seed), shape,
+                                                n_classes),
+            simple.fedavg_cnn_loss, simple.fedavg_cnn_accuracy, shape)
+
+
 def _transformer_triple(n_features, n_classes, kw):
     n_patches = kw.pop("n_patches", 8)
     if n_features % n_patches:
@@ -90,18 +100,26 @@ def _transformer_triple(n_features, n_classes, kw):
 
 
 _TRIPLES = {"softmax": _softmax_triple, "cnn": _cnn_triple,
+            "fedavg_cnn": _fedavg_cnn_triple,
             "transformer": _transformer_triple}
+_IMAGE_TRACKS = ("cnn", "fedavg_cnn")
+
+# A track's own defaults over ``default_config``'s shared ones. FedAvg's
+# CNN diverges at the shared lr (5e-3) and drifts at 1e-3; lr 3e-4 and
+# μ 0.015 come from a sweep on the chip.
+_TRACK_CONFIG = {"fedavg_cnn": dict(lr=3e-4, mu=0.015)}
 
 
 def make_task(name="softmax", **kw) -> NeuralTask:
     """Build a registered neural FedZO task.
 
-    ``name``: softmax | cnn | transformer. The data is a synthetic
-    class-conditional Gaussian problem (image-shaped and squashed to [0, 1]
-    pixels for the cnn track) split ``partition``-wise across ``n_clients``
-    (Dirichlet label skew by default; see ``_make_task`` for the data
-    defaults). Extra keywords reach the model builder (cnn: image_shape,
-    width; transformer: n_patches, n_layers, d_model, d_ff, n_heads).
+    ``name``: softmax | fedavg_cnn | cnn | transformer. The data is a
+    synthetic class-conditional Gaussian problem (image-shaped and squashed
+    to [0, 1] pixels for the image tracks) split ``partition``-wise across
+    ``n_clients`` (Dirichlet label skew by default; see ``_make_task`` for
+    the data defaults). Extra keywords reach the model constructor (cnn:
+    image_shape, width; fedavg_cnn: image_shape; transformer: n_patches,
+    n_layers, d_model, d_ff, n_heads).
     Cached: repeated calls with identical arguments (tests, benchmarks,
     figures) reuse the built store.
     """
@@ -120,7 +138,7 @@ def _make_task(name, *, n_train=2000, n_test=512, n_clients=10,
         raise ValueError(f"unknown neural task {name!r}; registered: "
                          f"{sorted(_TRIPLES)}")
     kw = dict(model_kw)
-    if name == "cnn":
+    if name in _IMAGE_TRACKS:
         shape = tuple(kw.get("image_shape") or (28, 28, 1))
         kw["image_shape"] = shape
         n_features = 1
@@ -163,11 +181,13 @@ def task_eval(task: NeuralTask, max_rows: int = 1024):
 def default_config(task: NeuralTask, **overrides) -> FedZOConfig:
     """Sec. V-B-shaped hyperparameters at container scale: partial
     participation, H=5 local iterates, b2=20 directions, size-weighted
-    aggregation for the skewed shards."""
+    aggregation for the skewed shards; a track's own defaults
+    (``_TRACK_CONFIG``) over these, and ``overrides`` over both."""
     kw = dict(n_devices=task.store.n_clients,
               n_participating=max(2, task.store.n_clients // 2),
               local_iters=5, lr=5e-3, mu=1e-3, b1=25, b2=20,
               weight_by_size=True)
+    kw.update(_TRACK_CONFIG.get(task.name, {}))
     kw.update(overrides)
     return FedZOConfig(**kw)
 

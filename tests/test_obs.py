@@ -321,11 +321,15 @@ def test_query_scope_nested_in_local_phase():
     paths = _scope_paths(_experiment_text(flat_params=True,
                                           flat_block_rows=8))
     assert any("fedzo.query" in p for p in paths)
+    assert any("fedzo.forward" in p for p in paths)
     for p in paths:
         if "fedzo.query" in p:
             assert "fedzo.local" in p
+        # the forward appears only inside a query
+        if "fedzo.forward" in p:
+            assert "fedzo.query" in p, p
         # the top-level layers never nest in one another
-        assert len(p - {"fedzo.query"}) <= 1, p
+        assert len(p - {"fedzo.query", "fedzo.forward"}) <= 1, p
 
 
 def test_sharded_round_scopes():
@@ -340,7 +344,8 @@ def test_sharded_round_scopes():
         softmax_loss, p, b, r, cfg, channel_rng=c)).lower(
             p0, batches, rngs, jax.random.key(2)).compile().as_text()
     seen = set().union(*_scope_paths(text))
-    assert seen == {"fedzo.local", "fedzo.query", "fedzo.aggregate"}
+    assert seen == {"fedzo.local", "fedzo.query", "fedzo.forward",
+                    "fedzo.aggregate"}
 
 
 def test_scope_refuses_unregistered_name():

@@ -220,3 +220,50 @@ def test_flat_experiment_kernels_sized_to_d(one_chip, monkeypatch):
                for rows in re.findall(r"f32\[(?:\d+,)*?(\d+),128\]", l)]
     assert blocked and set(blocked) == {"64"}, blocked
     assert not any("65536" in l or "512,128" in l for l in calls)
+
+
+def test_fedavg_cnn_experiment_at_published_widths(one_chip, monkeypatch):
+    """FedAvg's CNN (d = 1,663,370) on the flat-kernel plan, 2 rounds of
+    the paper's federation, compiles for the chip: every kernel is Mosaic,
+    its operands are [M, 13,104, 128] buffers walked in [504, 128] blocks
+    over 26 grid steps, and the forwards carry the ``fedzo.forward``
+    scope."""
+    import re
+
+    from repro import sim
+    from repro.kernels import ops
+    from repro.workloads import neural
+
+    monkeypatch.setattr(ops, "_auto_interpret",
+                        lambda i: False if i is None else i)
+    task = neural.make_task("fedavg_cnn", n_train=6000, n_test=1000,
+                            n_clients=50, partition="shards",
+                            image_shape=(28, 28, 1))
+    cfg = neural.default_config(task, n_participating=10, flat_params=True,
+                                direction_conv="counter")
+    fn = sim.make_experiment_fn(task.loss, cfg, 2)
+    params = jax.eval_shape(lambda: task.init(0))
+    assert sum(a.size for a in jax.tree.leaves(params)) == 1_663_370
+    jaxpr = str(jax.make_jaxpr(fn)(params, None, jax.random.key(0), None,
+                                   None, None, task.store))
+    grids = re.findall(r"grid=\(([^)]*)\)", jaxpr)
+    assert grids and all(g.split(",")[-1].strip() == "26" for g in grids), \
+        grids
+    assert "Blocked(block_size=504), Blocked(block_size=128)" in jaxpr
+
+    def sds(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                               sharding=one_chip)
+    text = fn.lower(sds(params), None, key, None, None, None,
+                    sds(task.store)).compile().as_text()
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    names = {re.sub(r"\.\d+$", "", re.search(r"%([\w.\-]+) = ", l)[1])
+             for l in calls}
+    assert names == {"zo_walk", "zo_replay", "zo_dirnorms"}, names
+    buffers = {rows for l in calls
+               for rows in re.findall(r"f32\[10,(\d+),128\]", l)}
+    assert buffers == {str(26 * 504)}, buffers
+    assert "fedzo.query/fedzo.forward/" in text
