@@ -12,7 +12,8 @@ per-client sizes, so both per-round draws happen inside jit:
 - ``sample_batches`` — H minibatches of size b1 per sampled client, uniform
   with replacement over that client's OWN rows (the same distribution as
   the host ``data.synthetic.sample_local_batches``), gathered straight from
-  the stacked arrays.
+  the stacked arrays: one row gather of the round's M·H·b1 (client, row)
+  pairs, so no op reads or copies a whole client shard.
 
 Padding rows are never sampled: the per-client ``randint`` upper bound is
 the client's true size, so the pad region is dead weight only
@@ -128,14 +129,11 @@ def sample_cohort_batches(data, sizes, key, h: int, b1: int):
     only on ``key`` and the client's true size — never on the cohort's
     padded capacity — so a bucket-padded staged cohort samples the exact
     rows the full-capacity resident store would (pad rows are unreachable
-    either way)."""
-    keys = jax.random.split(key, sizes.shape[0])
-
-    def one(d, n, k):
-        rows = jax.random.randint(k, (h, b1), 0, n)
-        return jax.tree.map(lambda l: l[rows], d)
-
-    return jax.vmap(one)(data, sizes, keys)
+    either way). It is ``sample_batches`` over the staged cohort with
+    ``idx = arange(M)``."""
+    m = sizes.shape[0]
+    return sample_batches(ClientStore(data=data, sizes=sizes),
+                          jnp.arange(m, dtype=jnp.int32), key, h, b1)
 
 
 def sample_batches(store: ClientStore, idx, key, h: int, b1: int):
@@ -143,8 +141,17 @@ def sample_batches(store: ClientStore, idx, key, h: int, b1: int):
 
     Per client: (h, b1) row indices uniform with replacement over
     [0, sizes[i]) — the in-jit twin of the host ``sample_local_batches``
-    (same distribution; the PRNG stream necessarily differs). Delegates to
-    ``sample_cohort_batches`` over the gathered cohort, so the resident
-    and tiered paths share one sampling derivation."""
-    cohort = jax.tree.map(lambda l: l[idx], store.data)
-    return sample_cohort_batches(cohort, store.sizes[idx], key, h, b1)
+    (same distribution; the PRNG stream necessarily differs) — drawn from
+    ``split(key, M)``, one key per cohort slot. Each leaf is read through
+    its row-major [N·cap, ...] view at the flat row ids ``idx[m]·cap +
+    row``: one gather of exactly the M·H·b1 rows the round uses, never a
+    copy of the sampled clients' whole shards."""
+    keys = jax.random.split(key, idx.shape[0])
+    rows = jax.vmap(lambda k, n: jax.random.randint(k, (h, b1), 0, n))(
+        keys, store.sizes[idx])
+    flat = idx[:, None, None] * store.capacity + rows
+
+    def gather(l):
+        return l.reshape((-1,) + l.shape[2:])[flat]
+
+    return jax.tree.map(gather, store.data)

@@ -7,8 +7,9 @@ Pins the subsystem's contracts:
   step and one key-chain protocol.
 - ``ClientStore`` sampling: participation draws are uniform M-of-N without
   replacement; minibatch rows are uniform-with-replacement over each
-  client's true size (the host ``sample_local_batches`` distribution) and
-  never touch padding.
+  client's true size (the host ``sample_local_batches`` distribution),
+  never touch padding, and are gathered straight from the store: bitwise
+  the rows the whole-shard gather read, with no [M, cap] intermediate.
 - The clients-axis shard_map round equals the single-device round on a
   1-device mesh (tight allclose — XLA fuses differently around the psum,
   so 1-ulp wiggle is expected; the math is identical).
@@ -230,6 +231,91 @@ def test_participation_draw_uniform_without_replacement():
 def test_build_store_validates_ragged_clients():
     with pytest.raises(ValueError, match="mismatched row counts"):
         sim.build_store([{"x": np.zeros((4, 2)), "y": np.zeros((3,))}])
+
+
+def _two_step_sample_batches(store, idx, key, h, b1):
+    """The whole-shard form ``sample_batches`` replaced, kept as its oracle:
+    gather the cohort's [M, cap, ...] shards, then draw and gather rows
+    per client."""
+    keys = jax.random.split(key, idx.shape[0])
+
+    def one(d, n, k):
+        rows = jax.random.randint(k, (h, b1), 0, n)
+        return jax.tree.map(lambda l: l[rows], d)
+
+    cohort = jax.tree.map(lambda l: l[idx], store.data)
+    return jax.vmap(one)(cohort, store.sizes[idx], keys)
+
+
+def _sampling_store(case):
+    """Small stores of every leaf layout the engine samples from: cap 17,
+    so no [M, H, b1] or [M·H·b1] shape can be mistaken for [M, cap]."""
+    rng = np.random.default_rng(0)
+    sizes = [17, 9, 13, 4, 17, 11] if case == "uneven" else [17] * 6
+
+    def client(n):
+        if case == "images":
+            return {"x": rng.random((n, 28, 28, 1), np.float32),
+                    "y": rng.integers(0, 10, n).astype(np.int32)}
+        if case == "labels_pytree":
+            return {"x": {"a": rng.normal(size=(n, 5)).astype(np.float32),
+                          "b": rng.normal(size=(n, 2, 3)).astype(np.float32)},
+                    "y": rng.integers(0, 10, n).astype(np.int32)}
+        return {"x": rng.normal(size=(n, 7)).astype(np.float32)}
+
+    return sim.build_store([client(n) for n in sizes])
+
+
+SAMPLING_CASES = ["uneven", "features_2d", "images", "labels_pytree"]
+
+
+@pytest.mark.parametrize("case", SAMPLING_CASES)
+def test_sample_batches_bitequal_to_whole_shard_gather(case):
+    """Gathering the round's (client, row) pairs straight from the store
+    reads the very rows, bit for bit, that gathering the cohort's whole
+    shards and then its rows did: the draws are unchanged."""
+    store = _sampling_store(case)
+    idx = jnp.asarray([3, 0, 5], jnp.int32)
+    key = jax.random.key(11)
+    want = jax.jit(lambda s, i, k: _two_step_sample_batches(s, i, k, 4, 5))(
+        store, idx, key)
+    got = jax.jit(lambda s, i, k: sim.sample_batches(s, i, k, 4, 5))(
+        store, idx, key)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for lw, lg in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+        assert lg.shape == lw.shape and lg.dtype == lw.dtype
+    _assert_trees_bitequal(want, got)
+
+
+def _intermediate_shapes(jaxpr):
+    """Every shape an equation of ``jaxpr`` (or of a jaxpr nested in one)
+    produces."""
+    out = []
+    for eqn in jaxpr.eqns:
+        out += [tuple(v.aval.shape) for v in eqn.outvars
+                if hasattr(v.aval, "shape")]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out += _intermediate_shapes(sub)
+    return out
+
+
+@pytest.mark.parametrize("case", SAMPLING_CASES)
+def test_sample_batches_builds_no_whole_shard_cohort(case):
+    """No op of the jitted sampler builds an [M, cap, ...] intermediate: the
+    round reads only the M·H·b1 rows it uses, never the cohort's whole
+    shards. The detector is checked against the whole-shard form."""
+    store = _sampling_store(case)
+    idx = jnp.asarray([3, 0, 5], jnp.int32)
+    m, cap = idx.shape[0], store.capacity
+
+    def cohort_shaped(fn):
+        jaxpr = jax.make_jaxpr(jax.jit(
+            lambda s, i, k: fn(s, i, k, 4, 5)))(store, idx, jax.random.key(0))
+        return [s for s in _intermediate_shapes(jaxpr.jaxpr)
+                if s[:2] == (m, cap)]
+
+    assert cohort_shaped(_two_step_sample_batches)
+    assert cohort_shaped(sim.sample_batches) == []
 
 
 # ---------------------------------------------------------------------------
