@@ -7,9 +7,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import FedZOConfig
-from repro.core.aircomp import (aircomp_aggregate, mask_stats,
-                                schedule_by_channel)
-from repro.utils.tree import tree_add, tree_axpy, tree_scale, tree_sub
+from repro.core.aircomp import cohort_mask, server_step_tree
+from repro.utils.tree import tree_axpy, tree_sub
 
 
 def local_phase(loss_fn, params, batches, cfg: FedZOConfig):
@@ -28,54 +27,24 @@ def round_simulated(loss_fn, server_params, client_batches, cfg: FedZOConfig,
                     channel=None):
     """One FedAvg round over M clients (batches leading axes [M, H, ...]).
 
-    Honors the same channel-truncation scheduling as the FedZO round
-    (cfg.channel_schedule): masked clients are excluded from the mean and
-    Δ_max, m_effective lands in the metrics. ``weights`` ([M] mean-1
-    normalized) selects the size-weighted n_i/n mean — the original
-    FedAvg aggregation — on every path. ``faults`` (a
-    ``sim.faults.RoundFaults``) corrupts-then-scrubs the deltas and folds
-    the surviving-client mask into the aggregation, same semantics as the
-    FedZO round (DESIGN.md §12).
+    The FedZO round's cohort mask and server step
+    (``aircomp.cohort_mask``, ``aircomp.server_step_tree``) after a
+    first-order local phase: channel-truncation scheduling or the realized
+    ``channel``, the size-weighted n_i/n mean under ``weights`` (the
+    original FedAvg aggregation), and ``faults`` scrubbed and masked out,
+    all with the FedZO round's semantics (DESIGN.md §12).
     """
     def one_client(batches):
         p_fin, losses = local_phase(loss_fn, server_params, batches, cfg)
         return tree_sub(p_fin, server_params), losses
 
+    M = jax.tree.leaves(client_batches)[0].shape[0]
+    mask, noise_rng = cohort_mask(cfg, M, channel_rng, channel)
     deltas, losses = jax.vmap(one_client)(client_batches)
-    M = losses.shape[0]
-    mask = None
-    noise_rng = channel_rng
-    stats = {}
-    if cfg.channel_schedule and channel_rng is not None:
-        k_sched, noise_rng = jax.random.split(channel_rng)
-        if channel is None:
-            _, mask = schedule_by_channel(k_sched, M, cfg.h_min)
-    if channel is not None:
-        # realized wireless scenario (sim/channel.py): correlated-fading
-        # scheduling ∧ battery gating replaces the i.i.d. draw
-        mask = channel.mask
-    if faults is not None:
-        deltas, fmask = faults.apply_tree(deltas)
-        mask = fmask if mask is None else mask & fmask
-    if cfg.aircomp and channel_rng is not None:
-        agg, stats = aircomp_aggregate(deltas, noise_rng, snr_db=cfg.snr_db,
-                                       h_min=cfg.h_min, mask=mask,
-                                       weights=weights)
-    elif mask is not None or weights is not None:
-        maskf, m_div, m_sched = mask_stats(mask, M, weights)
-        agg = jax.tree.map(
-            lambda x: (jnp.einsum("m...,m->...", x.astype(jnp.float32),
-                                  maskf) / m_div).astype(x.dtype), deltas)
-        # unconditional: weighted-but-unscheduled rounds must report the
-        # same cohort-size column as every other aggregation path
-        stats = {"m_effective": m_sched}
-    else:
-        agg = tree_scale(1.0 / M,
-                         jax.tree.map(lambda x: jnp.sum(x, 0), deltas))
-    if faults is not None:
-        stats["m_corrupt"] = faults.n_corrupt
-    return tree_add(server_params, agg), {"mean_local_loss": jnp.mean(losses),
-                                          **stats}
+    new_params, stats, _, _ = server_step_tree(
+        deltas, server_params, cfg, mask=mask, noise_rng=noise_rng,
+        weights=weights, faults=faults)
+    return new_params, {"mean_local_loss": jnp.mean(losses), **stats}
 
 
 def make_train_step(loss_fn, cfg: FedZOConfig):

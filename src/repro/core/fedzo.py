@@ -36,14 +36,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import FedZOConfig
-from repro.core import estimator
-from repro.core.aircomp import (aircomp_aggregate, aircomp_aggregate_flat,
-                                mask_stats, schedule_by_channel)
+from repro.core import aircomp, estimator
 from repro.kernels import ops as kops
+from repro.kernels.zo_axpy import LANES
 from repro.obs.trace import scope
 from repro.utils.flatparams import (flat_geometry, flat_spec, flatten,
                                     unflatten)
-from repro.utils.tree import tree_add, tree_scale, tree_sub
+from repro.utils.tree import tree_sub
 
 
 class LocalResult(NamedTuple):
@@ -52,36 +51,52 @@ class LocalResult(NamedTuple):
     losses: jnp.ndarray   # [H] base losses along the trajectory
 
 
-def _flat_setup(params, cfg: FedZOConfig):
-    """(spec, block_rows kwarg) for the cfg's flat-buffer geometry."""
-    return flat_geometry(params, cfg.flat_block_rows)
+def local_plan(params, cfg: FedZOConfig):
+    """The local phase's plan for ``params`` under ``cfg``:
+    ``(spec, block_rows, phase)``, or None on the pytree path (neither
+    cfg.batch_directions nor cfg.flat_params).
 
+    ``spec`` lays the params out as one flat buffer, ``block_rows`` is the
+    kernel block (None where no kernel reads the buffer), and
+    ``phase(loss_fn, buf0, keys, batches) -> (buf, coeffs [H, b2],
+    losses [H])`` runs one client's H iterates from ``buf0``. Two plans:
 
-def _wide_setup(params, cfg: FedZOConfig):
-    """Flat geometry for the batched-direction (wide) path.
-
-    The wide phase never enters a Pallas kernel, so it pads only to the
-    vector-lane width — NOT to the kernel block, whose extra rows every
-    [b2, n_pad] direction block would pay for. The kernel geometry is kept
-    only when the fused AirComp kernel consumes the delta matrix.
+    - wide (cfg.batch_directions, ``_wide_phase_scan``): it never enters a
+      Pallas kernel, so it pads only to the vector-lane width — NOT to the
+      kernel block, whose extra rows every [b2, n_pad] direction block
+      would pay for. The kernel geometry is kept only when the fused
+      AirComp kernel consumes the delta matrix;
+    - flat (cfg.flat_params, ``_flat_phase_scan``): the fused ZO kernels
+      on the cfg's kernel geometry (``flat_geometry``).
     """
-    from repro.kernels.zo_axpy import LANES
+    if cfg.batch_directions:
+        if cfg.aircomp:
+            spec, br = flat_geometry(params, cfg.flat_block_rows)
+        else:
+            spec, br = flat_spec(params, block=LANES), None
 
-    if cfg.aircomp:
-        return _flat_setup(params, cfg)
-    return flat_spec(params, block=LANES), None
+        def phase(loss_fn, buf0, keys, batches):
+            return _wide_phase_scan(loss_fn, buf0, spec, keys, batches, cfg,
+                                    like=params)
+    elif cfg.flat_params:
+        spec, br = flat_geometry(params, cfg.flat_block_rows)
+
+        def phase(loss_fn, buf0, keys, batches):
+            return _flat_phase_scan(loss_fn, buf0, spec, br, keys, batches,
+                                    cfg)
+    else:
+        return None
+    return spec, br, phase
 
 
 def flat_layout(params, cfg: FedZOConfig):
     """The flat buffer a run's local phase uses, for its manifest:
     ``{"d", "n_pad", "block_rows"}`` (block_rows None where no kernel
     runs), or None on the pytree path. n_pad / d is the pad ratio."""
-    if cfg.batch_directions:
-        spec, br = _wide_setup(params, cfg)
-    elif cfg.flat_params:
-        spec, br = _flat_setup(params, cfg)
-    else:
+    plan = local_plan(params, cfg)
+    if plan is None:
         return None
+    spec, br, _ = plan
     return {"d": spec.d, "n_pad": spec.n_pad, "block_rows": br}
 
 
@@ -114,7 +129,7 @@ def local_iterate(loss_fn, params, batch, rng, cfg: FedZOConfig):
     hot path when cfg.flat_params is set.
     """
     if cfg.flat_params:
-        spec, br = _flat_setup(params, cfg)
+        spec, br = flat_geometry(params, cfg.flat_block_rows)
         buf = flatten(params, spec)
         buf, coeffs, base = flat_local_iterate(loss_fn, buf, spec, batch,
                                                rng, cfg, block_rows=br)
@@ -260,17 +275,11 @@ def local_phase(loss_fn, params, batches, rng, cfg: FedZOConfig) -> LocalResult:
     keys = jax.random.split(rng, cfg.local_iters)
     _check_surrogate(cfg)
 
-    if cfg.batch_directions:
-        spec, _ = _wide_setup(params, cfg)
-        buf, coeffs, losses = _wide_phase_scan(
-            loss_fn, flatten(params, spec), spec, keys, batches, cfg,
-            like=params)
-        return LocalResult(unflatten(buf, spec), coeffs, losses)
-
-    if cfg.flat_params:
-        spec, br = _flat_setup(params, cfg)
-        buf, coeffs, losses = _flat_phase_scan(
-            loss_fn, flatten(params, spec), spec, br, keys, batches, cfg)
+    plan = local_plan(params, cfg)
+    if plan is not None:
+        spec, _, phase = plan
+        buf, coeffs, losses = phase(loss_fn, flatten(params, spec), keys,
+                                    batches)
         return LocalResult(unflatten(buf, spec), coeffs, losses)
 
     def body(carry, inp):
@@ -297,177 +306,69 @@ def round_simulated(loss_fn, server_params, client_batches, client_rngs,
 
     client_batches: pytree with leading [M, H, ...] axes.
     client_rngs:    [M] PRNG keys.
-    ``momentum``: optional server-momentum state (FedOpt-style — beyond
-    paper); pass a zeros-like tree and cfg.server_momentum > 0 to enable.
-    Returns (new_server_params, metrics dict[, new_momentum]).
+    Returns (new_server_params, metrics dict[, new_momentum][, cstate]).
 
-    With cfg.flat_params the whole round runs on the flat buffer
-    (DESIGN.md §8): the server params are flattened ONCE, the flat local
-    phase is vmapped over the M clients so the client deltas materialize
-    as one [M, n_pad] matrix, and aggregation (masked mean or the fused
-    one-pass AirComp kernel) happens on that matrix before a single
-    unflatten.
+    The round is three steps: ``aircomp.cohort_mask`` resolves the round's
+    channel mask (``channel_rng``, ``channel``); the local phase runs
+    vmapped over the M clients; the server step turns the deltas into new
+    params. With a ``local_plan`` (cfg.flat_params or
+    cfg.batch_directions) the whole round runs on the flat buffer
+    (DESIGN.md §8): the server params are flattened ONCE, the deltas
+    materialize as one [M, n_pad] matrix and ``aircomp.server_step_flat``
+    aggregates it before a single unflatten. Otherwise the pytree local
+    phase and ``aircomp.server_step_tree`` run: the reference the goldens
+    pin. ``momentum``, ``weights``, ``faults``, ``cstate`` and
+    ``state_fn`` are the server step's (documented there); ``momentum``
+    comes back when passed, ``cstate`` too.
 
-    cfg.channel_schedule enables the paper's channel-truncation scheduling
-    (Sec. IV-A): a Rayleigh draw from ``channel_rng`` masks out clients
-    with |h| < h_min; masked rows are excluded from both the mean and
-    Δ_max and ``m_effective`` is reported in the metrics.
-
-    ``weights`` ([M] positive, mean-1 normalized — ``aircomp.size_weights``)
-    switches every aggregation path to the FedAvg-style size-weighted mean
-    n_i/n over the (scheduled) clients; the engine threads it from
-    ``ClientStore.sizes`` under ``cfg.weight_by_size``.
-
-    ``faults`` (a ``sim.faults.RoundFaults``) injects this round's realized
-    client faults: the deltas are corrupted-then-scrubbed before
-    aggregation and the surviving-client mask composes with the channel
-    mask, so dropped/straggling/poisoned clients are excluded from the
-    mean and Δ_max exactly like channel-masked ones (DESIGN.md §12).
-
-    ``channel`` (a ``sim.channel.RoundChannel``) supplies this round's
-    realized wireless scenario (DESIGN.md §16): its transmit mask —
-    time-correlated-fading scheduling ∧ battery gating, advanced by the
-    engine's ``ChannelModel`` carry step — REPLACES the i.i.d.
-    ``schedule_by_channel`` draw, composing with faults and weights
-    through the same ``mask_stats`` convention. ``channel=None`` keeps
-    the per-round i.i.d. draw bit-exactly.
-
-    Strategy hooks (core/strategy.py, DESIGN.md §13) — all default None,
-    in which case every code path above is byte-for-byte the plain FedZO
-    round:
-
-    - ``cstate``: the [M, ...] per-client strategy state of the sampled
-      cohort (SCAFFOLD control variates, FedDyn duals), vmapped alongside
-      the batches; the (possibly updated) cohort state is appended to the
-      return tuple whenever ``cstate`` is passed.
-    - ``loss_wrap(loss_fn, cst) -> loss_fn'`` wraps the ZO loss query per
-      client (proximal term, dynamic regularizer) — the estimator itself
-      is untouched.
-    - ``state_fn(deltas, cstate, spec) -> (deltas', cstate')`` is the
-      client-side post-phase delta correction, applied in flat [M, n_pad]
-      space on the flat/wide paths (``spec`` set) and on the stacked delta
-      pytree otherwise (``spec=None``) — BEFORE fault corruption and the
-      aggregation, so it composes with AirComp, scheduling, weighting,
-      and the sharded reduce unchanged.
+    ``loss_wrap(loss_fn, cst) -> loss_fn'`` wraps each client's ZO loss
+    query with its strategy state (proximal term, dynamic regularizer —
+    core/strategy.py, DESIGN.md §13); ``cstate``, the [M, ...] cohort
+    strategy state, is vmapped alongside the batches. With every hook None
+    the round is byte-for-byte the plain FedZO round.
     """
-    M = client_rngs.shape[0]
     _check_surrogate(cfg)
-    new_cstate = cstate
-    mask = None
-    noise_rng = channel_rng
-    air_stats = {}
-    if cfg.channel_schedule and channel_rng is not None:
-        with scope("fedzo.cohort"):
-            k_sched, noise_rng = jax.random.split(channel_rng)
-            if channel is None:
-                _, mask = schedule_by_channel(k_sched, M, cfg.h_min)
-    if channel is not None:
-        # the scenario engine realized this round's channel already:
-        # correlated-fading scheduling ∧ battery gating (sim/channel.py)
-        mask = channel.mask
+    mask, noise_rng = aircomp.cohort_mask(cfg, client_rngs.shape[0],
+                                          channel_rng, channel)
+    step_kw = dict(mask=mask, noise_rng=noise_rng, weights=weights,
+                   faults=faults, momentum=momentum, state_fn=state_fn,
+                   cstate=cstate)
+    per_client = () if cstate is None else (cstate,)
 
-    if cfg.flat_params or cfg.batch_directions:
+    def client_loss(cst):
+        return loss_fn if loss_wrap is None else loss_wrap(loss_fn, cst)
+
+    plan = local_plan(server_params, cfg)
+    if plan is None:
+        def one_client(batches, rng, cst=None):
+            delta, res = client_delta(client_loss(cst), server_params,
+                                      batches, rng, cfg)
+            return delta, res.losses
+
         with scope("fedzo.local"):
-            spec, br = (_wide_setup(server_params, cfg)
-                        if cfg.batch_directions
-                        else _flat_setup(server_params, cfg))
+            deltas, losses = jax.vmap(one_client)(client_batches,
+                                                  client_rngs, *per_client)
+        new_params, stats, momentum, new_cstate = aircomp.server_step_tree(
+            deltas, server_params, cfg, **step_kw)
+    else:
+        spec, br, phase = plan
+        with scope("fedzo.local"):
             buf0 = flatten(server_params, spec)
             keys = jax.vmap(lambda r: jax.random.split(r, cfg.local_iters))(
                 client_rngs)
 
             def one_client(batches, ks, cst=None):
-                lf = (loss_wrap(loss_fn, cst) if loss_wrap is not None
-                      else loss_fn)
-                if cfg.batch_directions:
-                    buf, _, base = _wide_phase_scan(lf, buf0, spec, ks,
-                                                    batches, cfg,
-                                                    like=server_params)
-                else:
-                    buf, _, base = _flat_phase_scan(lf, buf0, spec, br, ks,
-                                                    batches, cfg)
+                buf, _, base = phase(client_loss(cst), buf0, ks, batches)
                 return buf - buf0, base
 
-            if cstate is not None:
-                deltas, losses = jax.vmap(one_client)(client_batches, keys,
-                                                      cstate)
-            else:
-                deltas, losses = jax.vmap(one_client)(client_batches, keys)
-
-        with scope("fedzo.aggregate"):
-            if state_fn is not None:
-                deltas, new_cstate = state_fn(deltas, cstate, spec)
-
-            if faults is not None:
-                deltas, fmask = faults.apply_flat(deltas)
-                mask = fmask if mask is None else mask & fmask
-
-            if cfg.aircomp and channel_rng is not None:
-                agg_flat, air_stats = aircomp_aggregate_flat(
-                    deltas, noise_rng, snr_db=cfg.snr_db, h_min=cfg.h_min,
-                    d=spec.d, mask=mask, weights=weights, block_rows=br)
-            elif mask is not None or weights is not None:
-                maskf, m_div, m_sched = mask_stats(mask, M, weights)
-                agg_flat = jnp.einsum("mn,m->n", deltas, maskf) / m_div
-                # m_effective reports unconditionally: a weighted-but-
-                # unscheduled round must carry the same cohort-size column
-                # as every other aggregation path (history/CSV consistency)
-                air_stats = {"m_effective": m_sched}
-            else:
-                agg_flat = jnp.mean(deltas, axis=0)
-            agg = unflatten(agg_flat, spec)
-    else:
-        def one_client(batches, rng, cst=None):
-            lf = loss_wrap(loss_fn, cst) if loss_wrap is not None else loss_fn
-            delta, res = client_delta(lf, server_params, batches, rng, cfg)
-            return delta, res.losses
-
-        with scope("fedzo.local"):
-            if cstate is not None:
-                deltas, losses = jax.vmap(one_client)(client_batches,
-                                                      client_rngs, cstate)
-            else:
-                deltas, losses = jax.vmap(one_client)(client_batches,
-                                                      client_rngs)
-
-        with scope("fedzo.aggregate"):
-            if state_fn is not None:
-                deltas, new_cstate = state_fn(deltas, cstate, None)
-
-            if faults is not None:
-                deltas, fmask = faults.apply_tree(deltas)
-                mask = fmask if mask is None else mask & fmask
-
-            if cfg.aircomp and channel_rng is not None:
-                agg, air_stats = aircomp_aggregate(
-                    deltas, noise_rng, snr_db=cfg.snr_db, h_min=cfg.h_min,
-                    mask=mask, weights=weights)
-            elif mask is not None or weights is not None:
-                maskf, m_div, m_sched = mask_stats(mask, M, weights)
-                agg = jax.tree.map(
-                    lambda x: (jnp.einsum("m...,m->...",
-                                          x.astype(jnp.float32),
-                                          maskf) / m_div).astype(x.dtype),
-                    deltas)
-                air_stats = {"m_effective": m_sched}  # see flat-path comment
-            else:
-                agg = tree_scale(1.0 / M,
-                                 jax.tree.map(lambda x: jnp.sum(x, 0),
-                                              deltas))
+            deltas, losses = jax.vmap(one_client)(client_batches, keys,
+                                                  *per_client)
+        new_params, stats, momentum, new_cstate = aircomp.server_step_flat(
+            deltas, server_params, cfg, spec, br, **step_kw)
 
     with scope("fedzo.aggregate"):
-        if momentum is not None and cfg.server_momentum > 0:
-            momentum = jax.tree.map(
-                lambda m, g: (cfg.server_momentum * m + g).astype(m.dtype),
-                momentum, agg)
-            agg = momentum
-        new_params = tree_add(server_params, agg)
-        if faults is not None:
-            # mask is never None under faults, so every branch above
-            # reported m_effective (the surviving cohort); add the poison
-            # count
-            air_stats["m_corrupt"] = faults.n_corrupt
         metrics = {"mean_local_loss": jnp.mean(losses),
-                   "first_loss": jnp.mean(losses[:, 0]), **air_stats}
+                   "first_loss": jnp.mean(losses[:, 0]), **stats}
     out = (new_params, metrics)
     if momentum is not None:
         out = out + (momentum,)
@@ -504,7 +405,7 @@ def make_pod_round_step(loss_fn_grouped, cfg: FedZOConfig, mesh) -> Callable:
 
     if cfg.flat_params:
         def flat_step(params, batch, rng):
-            spec, br = _flat_setup(params, cfg)
+            spec, br = flat_geometry(params, cfg.flat_block_rows)
             buf = flatten(params, spec)
             # sphere inv-norms computed ONCE and shared by both ends — the
             # same invariant flat_local_iterate documents (zo_dirnorms
@@ -566,14 +467,8 @@ def make_delta_agg_step(cfg: FedZOConfig, n_pod: int) -> Callable:
     prices the full-d cross-pod all-reduce that AirComp / seed-compression
     eliminate. signature: (deltas, rng) -> tree
     """
-    from repro.core.aircomp import aircomp_aggregate
-
     def step(deltas, rng):
-        if cfg.aircomp:
-            agg, _ = aircomp_aggregate(deltas, rng, snr_db=cfg.snr_db,
-                                       h_min=cfg.h_min)
-            return agg
-        return jax.tree.map(lambda x: jnp.mean(x, axis=0), deltas)
+        return aircomp.aggregate_tree(deltas, cfg, noise_rng=rng)[0]
 
     return step
 

@@ -5,13 +5,13 @@ The flat simulation round materializes the M client deltas as one
 [M, n_pad] matrix (core/fedzo.py §8). Here that matrix — and the vmapped
 local phases that produce it — are split across devices with ``shard_map``:
 each device runs M/n_dev local phases on its shard of the per-round batches
-and reduces its rows first (partial fused AirComp reduce or partial masked
-einsum), so the only cross-device exchange is one n_pad-sized psum of
-partial means plus the [M] row norms. Everything downstream of the reduce
-(Δ_max, Eq.-17 noise, momentum, metrics) runs on the replicated result with
-EXACTLY the ops of ``fedzo.round_simulated`` — on a 1-device mesh the
-sharded round is bit-identical to the unsharded one, which is what the
-equivalence test pins.
+and then the flat server step (``aircomp.server_step_flat``) on its own
+rows, with a ``CohortReduce`` that sums partial means and row counts with
+``jax.lax.psum`` and maxes Δ_max with ``jax.lax.pmax`` across devices. So
+the only cross-device exchange is one n_pad-sized psum of partial means plus
+scalars, and the ops are those of ``fedzo.round_simulated``: on a 1-device
+mesh the sharded round matches the unsharded one, which is what the
+equivalence tests pin.
 
 The returned round is a drop-in ``round_fn`` for
 ``sim.engine.make_round_step``, so a whole sharded experiment still runs as
@@ -24,14 +24,11 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import FedZOConfig
-from repro.core.aircomp import P_TX, mask_stats, schedule_by_channel
-from repro.core.fedzo import (_flat_phase_scan, _flat_setup,
-                              _wide_phase_scan, _wide_setup)
-from repro.kernels import ops as kops
+from repro.core.aircomp import CohortReduce, cohort_mask, server_step_flat
+from repro.core.fedzo import local_plan
 from repro.launch.mesh import make_clients_mesh  # noqa: F401  (re-export)
 from repro.obs.trace import scope
-from repro.utils.flatparams import flatten, unflatten
-from repro.utils.tree import tree_add
+from repro.utils.flatparams import flatten
 
 
 def make_sharded_round(loss_fn, cfg: FedZOConfig, mesh: Mesh, *,
@@ -61,9 +58,14 @@ def make_sharded_round(loss_fn, cfg: FedZOConfig, mesh: Mesh, *,
                 f"n_participating={cfg.n_participating} must divide evenly "
                 f"over the {n_dev}-device '{axis}' mesh axis")
 
+    # psum and pmax are read through this module's ``jax`` as the round
+    # traces
+    reduce = CohortReduce(sum=lambda x: jax.lax.psum(x, axis),
+                          max=lambda x: jax.lax.pmax(x, axis))
+
     def round_fn(loss_fn_, server_params, client_batches, client_rngs, cfg_,
                  *, channel_rng=None, momentum=None, weights=None,
-                 faults=None):
+                 faults=None, channel=None):
         if loss_fn_ is not loss_fn or cfg_ is not cfg:
             # the mesh deployment (phase choice, geometry, device split) is
             # bound at construction — a per-call substitution would silently
@@ -75,146 +77,42 @@ def make_sharded_round(loss_fn, cfg: FedZOConfig, mesh: Mesh, *,
         if M % n_dev:
             raise ValueError(f"n_participating={M} must divide evenly over "
                              f"the {n_dev}-device '{axis}' mesh axis")
+        mask, noise_rng = cohort_mask(cfg, M, channel_rng, channel)
+        spec, br, phase = local_plan(server_params, cfg)
         with scope("fedzo.local"):
-            spec, br = (_wide_setup(server_params, cfg)
-                        if cfg.batch_directions
-                        else _flat_setup(server_params, cfg))
             buf0 = flatten(server_params, spec)
 
-        mask = None
-        noise_rng = channel_rng
-        air_stats = {}
-        if cfg.channel_schedule and channel_rng is not None:
-            with scope("fedzo.cohort"):
-                k_sched, noise_rng = jax.random.split(channel_rng)
-                _, mask = schedule_by_channel(k_sched, M, cfg.h_min)
-        use_air = cfg.aircomp and channel_rng is not None
-        # size weighting rides the same per-row coefficient vector the mask
-        # does, so the weighted round shards identically to the masked one
-        use_rowcoef = mask is not None or weights is not None
-        with scope("fedzo.aggregate"):
-            maskf, m_div, m_sched = mask_stats(mask, M, weights)
+        def body(b0, params, mom, noise_key, batches_l, rngs_l, mask_l, w_l,
+                 fmask_l, corrupt_l):
+            with scope("fedzo.local"):
+                keys = jax.vmap(lambda r: jax.random.split(
+                    r, cfg.local_iters))(rngs_l)
 
-        def local_deltas(b0, params, batches_l, rngs_l):
-            keys = jax.vmap(lambda r: jax.random.split(
-                r, cfg.local_iters))(rngs_l)
-
-            if cfg.batch_directions:
                 def one_client(batches, ks):
-                    buf, _, base = _wide_phase_scan(loss_fn, b0, spec, ks,
-                                                    batches, cfg,
-                                                    like=params)
-                    return buf - b0, base
-            else:
-                def one_client(batches, ks):
-                    buf, _, base = _flat_phase_scan(loss_fn, b0, spec, br,
-                                                    ks, batches, cfg)
+                    buf, _, base = phase(loss_fn, b0, ks, batches)
                     return buf - b0, base
 
-            return jax.vmap(one_client)(batches_l, keys)
+                deltas_l, losses_l = jax.vmap(one_client)(batches_l, keys)
+            faults_l = (None if faults is None else
+                        faults._replace(mask=fmask_l, corrupt=corrupt_l))
+            new_params, stats, mom, _ = server_step_flat(
+                deltas_l, params, cfg, spec, br, mask=mask_l,
+                noise_rng=noise_key, weights=w_l, faults=faults_l,
+                momentum=mom, reduce=reduce)
+            return new_params, stats, mom, losses_l
 
-        def shard_body(b0, params, batches_l, rngs_l, maskf_l):
-            with scope("fedzo.local"):
-                deltas_l, losses_l = local_deltas(b0, params, batches_l,
-                                                  rngs_l)
-
-            with scope("fedzo.aggregate"):
-                if use_air:
-                    part, sq_l = kops.aircomp_reduce(
-                        deltas_l, maskf_l / m_div, spec.d, block_rows=br)
-                    mean = jax.lax.psum(part, axis)
-                elif use_rowcoef:
-                    part = jnp.einsum("mn,m->n", deltas_l, maskf_l)
-                    mean = jax.lax.psum(part, axis) / m_div
-                    sq_l = jnp.zeros((deltas_l.shape[0],), jnp.float32)
-                else:
-                    part = jnp.sum(deltas_l, axis=0)
-                    mean = jax.lax.psum(part, axis) / M
-                    sq_l = jnp.zeros((deltas_l.shape[0],), jnp.float32)
-            return mean, sq_l, losses_l
-
-        def shard_body_faults(b0, params, batches_l, rngs_l, chan_l, w_l,
-                              fmask_l, corrupt_l):
-            """Fault variant: the guard verdict (and with it the surviving
-            cohort and the mean divisor) is only known per-shard, so the
-            scrub runs on each device's rows and the divisor is a psum of
-            per-shard coefficient sums — mirroring ``mask_stats`` on the
-            combined channel ∧ fault mask bit-for-bit on one device."""
-            with scope("fedzo.local"):
-                deltas_l, losses_l = local_deltas(b0, params, batches_l,
-                                                  rngs_l)
-            with scope("fedzo.aggregate"):
-                deltas_l, ok_l = faults.model.scrub(deltas_l, fmask_l,
-                                                    corrupt_l)
-                combined_l = (chan_l & ok_l).astype(jnp.float32)
-                n_sched = jax.lax.psum(jnp.sum(combined_l), axis)
-                coef_l = combined_l * w_l
-                if weights is None:
-                    div = jnp.maximum(n_sched, 1.0)
-                else:
-                    div = jnp.maximum(jax.lax.psum(jnp.sum(coef_l), axis),
-                                      1e-8)
-                if use_air:
-                    part, sq_l = kops.aircomp_reduce(deltas_l, coef_l / div,
-                                                     spec.d, block_rows=br)
-                    mean = jax.lax.psum(part, axis)
-                else:
-                    part = jnp.einsum("mn,m->n", deltas_l, coef_l)
-                    mean = jax.lax.psum(part, axis) / div
-                    sq_l = jnp.zeros((deltas_l.shape[0],), jnp.float32)
-            return mean, sq_l, losses_l, coef_l, div, n_sched
-
-        if faults is not None:
-            chan = (jnp.ones((M,), jnp.bool_) if mask is None else mask)
-            w = (jnp.ones((M,), jnp.float32) if weights is None
-                 else weights.astype(jnp.float32))
-            agg_flat, sq, losses, maskf, m_div, m_sched = jax.shard_map(
-                shard_body_faults, mesh=mesh,
-                in_specs=(P(), P(), P(axis), P(axis), P(axis), P(axis),
-                          P(axis), P(axis)),
-                out_specs=(P(), P(axis), P(axis), P(axis), P(), P()),
-                check_vma=False)(buf0, server_params, client_batches,
-                                 client_rngs, chan, w, faults.mask,
-                                 faults.corrupt)
-        else:
-            agg_flat, sq, losses = jax.shard_map(
-                shard_body, mesh=mesh,
-                in_specs=(P(), P(), P(axis), P(axis), P(axis)),
-                out_specs=(P(), P(axis), P(axis)),
-                check_vma=False)(buf0, server_params, client_batches,
-                                 client_rngs, maskf)
-
+        fmask, corrupt = ((None, None) if faults is None
+                          else (faults.mask, faults.corrupt))
+        new_params, stats, momentum, losses = jax.shard_map(
+            body, mesh=mesh,
+            in_specs=(P(), P(), P(), P()) + (P(axis),) * 6,
+            out_specs=(P(), P(), P(), P(axis)),
+            check_vma=False)(buf0, server_params, momentum, noise_rng,
+                             client_batches, client_rngs, mask, weights,
+                             fmask, corrupt)
         with scope("fedzo.aggregate"):
-            if use_air:
-                # Δ_max / Eq.-17 noise on the replicated mean: literally the
-                # tail of aircomp_aggregate_flat, fed by the psum'd partials
-                sigma_w2 = P_TX / (10.0 ** (cfg.snr_db / 10.0))
-                delta_max = jnp.max(jnp.where(maskf > 0, sq, 0.0))
-                noise_var = sigma_w2 * delta_max / (
-                    m_div ** 2 * float(spec.d) * P_TX * cfg.h_min ** 2)
-                noise_std = jnp.sqrt(noise_var)
-                agg_flat = kops.zo_walk(
-                    agg_flat, kops.key_words(noise_rng),
-                    jnp.zeros((2,), jnp.int32),
-                    jnp.stack([noise_std, jnp.float32(0.0)]),
-                    kind="normal", block_rows=br)
-                air_stats = {"aircomp_noise_std": noise_std,
-                             "delta_max": delta_max, "m_effective": m_sched}
-            elif mask is not None or faults is not None:
-                air_stats = {"m_effective": m_sched}
-            if faults is not None:
-                air_stats["m_corrupt"] = faults.n_corrupt
-
-            agg = unflatten(agg_flat, spec)
-            if momentum is not None and cfg.server_momentum > 0:
-                momentum = jax.tree.map(
-                    lambda m, g: (cfg.server_momentum * m
-                                  + g).astype(m.dtype),
-                    momentum, agg)
-                agg = momentum
-            new_params = tree_add(server_params, agg)
             metrics = {"mean_local_loss": jnp.mean(losses),
-                       "first_loss": jnp.mean(losses[:, 0]), **air_stats}
+                       "first_loss": jnp.mean(losses[:, 0]), **stats}
         if momentum is not None:
             return new_params, metrics, momentum
         return new_params, metrics

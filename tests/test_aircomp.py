@@ -4,11 +4,16 @@ aggregation kernel (kernels/zo_aircomp.py)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from _hyp import hypothesis, st
 
+from repro.configs.base import FedZOConfig
+from repro.core import aircomp
 from repro.core.aircomp import (aircomp_aggregate, aircomp_aggregate_flat,
                                 aircomp_simulate_channel, schedule_by_channel)
 from repro.kernels import ops, ref
+from repro.sim.faults import FaultModel, RoundFaults
+from repro.utils.flatparams import flat_geometry, flatten
 
 BR = 4  # small kernel blocks for CPU interpret mode: 4 rows × 128 lanes
 
@@ -182,6 +187,77 @@ def test_fused_flat_matches_pytree_aggregate():
                                atol=1e-5)
     for k in ("delta_max", "m_effective", "aircomp_noise_std"):
         np.testing.assert_allclose(float(s_f[k]), float(s_t[k]), rtol=1e-5)
+
+
+# rows kept, size weights and faults of the shared server-step cases (M=5)
+STEP_CASES = {
+    "mean": {},
+    "mask": {"mask": [True, False, True, True, False]},
+    "weights": {"weights": [0.5, 1.5, 1.0, 0.25, 1.75]},
+    "faults": {"weights": [0.5, 1.5, 1.0, 0.25, 1.75],
+               "avail": [True, True, False, True, True],
+               "corrupt": [False, True, False, False, False]},
+    "aircomp": {"mask": [True, False, True, True, False], "aircomp": True},
+}
+
+
+@pytest.mark.parametrize("case", sorted(STEP_CASES))
+def test_server_steps_agree_on_fixed_deltas(case):
+    """``server_step_tree`` and ``server_step_flat`` turn the same hand-made
+    deltas into the same params, momentum and metrics, and both equal
+    x + β·m + Σ c_i Δ_i / Σ c_i over the rows kept (c = mask·w; a poisoned
+    row is scrubbed, an unavailable one dropped). AirComp runs at 200 dB,
+    where its noise is far below the tolerance and its statistics remain."""
+    c = STEP_CASES[case]
+    M, beta = 5, 0.5
+    rng = np.random.default_rng(4)
+    params = {"b": jnp.asarray(rng.normal(size=(3,)), jnp.float32),
+              "w": jnp.asarray(rng.normal(size=(6, 3)), jnp.float32)}
+    deltas = jax.tree.map(lambda p: jnp.asarray(
+        rng.normal(size=(M,) + p.shape), jnp.float32), params)
+    momentum = jax.tree.map(lambda p: jnp.full_like(p, 0.1), params)
+    cfg = FedZOConfig(aircomp=c.get("aircomp", False), snr_db=200.0,
+                      server_momentum=beta)
+    kw = {"momentum": momentum}
+    keep = np.ones(M, bool)
+    w = np.ones(M, np.float32)
+    if "mask" in c:
+        kw["mask"] = jnp.asarray(c["mask"])
+        keep &= c["mask"]
+    if "weights" in c:
+        w = np.asarray(c["weights"], np.float32)
+        kw["weights"] = jnp.asarray(w)
+    if "avail" in c:
+        kw["faults"] = RoundFaults(FaultModel(p_corrupt=0.5),
+                                   jnp.asarray(c["avail"]),
+                                   jnp.asarray(c["corrupt"]))
+        keep &= np.asarray(c["avail"]) & ~np.asarray(c["corrupt"])
+    if cfg.aircomp:
+        kw["noise_rng"] = jax.random.key(3)
+    spec, br = flat_geometry(params, BR)
+    flat = jax.vmap(lambda t: flatten(t, spec))(deltas)
+
+    p_t, s_t, m_t, _ = aircomp.server_step_tree(deltas, params, cfg, **kw)
+    p_f, s_f, m_f, _ = aircomp.server_step_flat(flat, params, cfg, spec, br,
+                                                **kw)
+    coef = keep * w
+    for k in params:
+        agg = np.einsum("m...,m->...", np.asarray(deltas[k]), coef) \
+            / coef.sum()
+        want_m = beta * 0.1 + agg
+        for got_p, got_m in ((p_t, m_t), (p_f, m_f)):
+            np.testing.assert_allclose(np.asarray(got_m[k]), want_m,
+                                       atol=1e-5)
+            np.testing.assert_allclose(np.asarray(got_p[k]),
+                                       np.asarray(params[k]) + want_m,
+                                       atol=1e-5)
+    assert set(s_t) == set(s_f)
+    for k in s_t:
+        np.testing.assert_allclose(float(s_f[k]), float(s_t[k]), rtol=1e-5)
+    if case != "mean":
+        assert float(s_t["m_effective"]) == keep.sum()
+    if "avail" in c:
+        assert float(s_t["m_corrupt"]) == 1.0
 
 
 def test_fused_flat_noise_variance_matches_closed_form():

@@ -328,11 +328,18 @@ def test_sample_batches_builds_no_whole_shard_cohort(case):
      "snr_db": 10.0, "channel_schedule": True},
     {"flat_params": True, "flat_block_rows": BR, "aircomp": True,
      "snr_db": 10.0},
+    {"batch_directions": True, "direction_conv": "block",
+     "weight_by_size": True},
+    {"flat_params": True, "flat_block_rows": BR, "aircomp": True,
+     "snr_db": 10.0, "channel_schedule": True,
+     "channel_model": sim.ChannelModel.from_doppler(0.02)},
 ])
 def test_sharded_round_matches_single_device(kw):
     """shard_map over a 1-device 'clients' mesh == the unsharded round.
     Tight allclose, not bitwise: the psum boundary changes XLA's fusion
-    choices by ~1 ulp even though the reduction math is identical."""
+    choices by ~1 ulp even though the reduction math is identical. Size
+    weights and a realized ``ChannelModel`` channel reach both rounds as
+    the engine hands them over, and both report the same metrics."""
     clients, store = _setup()
     cfg = _cfg(**kw)
     p0 = softmax_init(None, 24, 4)
@@ -342,13 +349,25 @@ def test_sharded_round_matches_single_device(kw):
                                  cfg.local_iters, cfg.b1)
     rngs = jax.random.split(jax.random.key(1), 4)
     kc = jax.random.key(2)
+    wkw = {}
+    if cfg.weight_by_size:
+        wkw["weights"] = aircomp.size_weights(jnp.asarray([50, 80, 110,
+                                                           140]))
+    if cfg.channel_model is not None:
+        cm = cfg.channel_model
+        _, wkw["channel"] = cm.step(
+            jax.random.key(6), cm.init_state(8, jax.random.key(5)),
+            jnp.arange(4), h_min=cfg.h_min, schedule=cfg.channel_schedule)
     ref = jax.jit(lambda p, b, r, c: fedzo.round_simulated(
-        softmax_loss, p, b, r, cfg, channel_rng=c))(p0, batches, rngs, kc)
+        softmax_loss, p, b, r, cfg, channel_rng=c, **wkw))(p0, batches,
+                                                           rngs, kc)
     got = jax.jit(lambda p, b, r, c: rf(
-        softmax_loss, p, b, r, cfg, channel_rng=c))(p0, batches, rngs, kc)
+        softmax_loss, p, b, r, cfg, channel_rng=c, **wkw))(p0, batches,
+                                                           rngs, kc)
     for la, lb in zip(jax.tree.leaves(ref[0]), jax.tree.leaves(got[0])):
         np.testing.assert_allclose(np.asarray(la), np.asarray(lb),
                                    atol=1e-7, rtol=1e-6)
+    assert set(ref[1]) == set(got[1])
     for k in ref[1]:
         np.testing.assert_allclose(float(ref[1][k]), float(got[1][k]),
                                    rtol=1e-6)

@@ -253,8 +253,8 @@ D_SOFTMAX = 7850            # the paper's softmax regression, 784·10 + 10
 
 
 def test_derived_geometry_is_one_64_row_block_at_softmax_d():
-    spec, br = fedzo._flat_setup(softmax_init(None),
-                                 FedZOConfig(flat_params=True))
+    spec, br, _ = fedzo.local_plan(softmax_init(None),
+                                   FedZOConfig(flat_params=True))
     assert (spec.d, br, spec.n_pad) == (D_SOFTMAX, 64, 8192)
 
 
