@@ -14,9 +14,10 @@ Two kinds of rows:
   scale read+write 2d → 2.0 passes) + tree_axpy x+μv (read x, read v,
   write → 1.5 passes) = 3.5 passes/direction, and the update replays b2
   more axpy passes (3.5 each). The fused flat path regenerates directions
-  in VMEM: zo_walk = read x + write x = 1.0 pass/direction (≤ 2 by a 2×
-  margin), and zo_replay folds the whole b2-direction update into 1.0
-  pass total.
+  in VMEM: each zo_walk step reads and writes x and the update's fp32
+  accumulator u (2.0 passes/direction), and the update costs 2.5 passes
+  in total: the closing call's pass over u, then x + u (read both, write
+  one).
 """
 from __future__ import annotations
 
@@ -37,10 +38,11 @@ def traffic_model(d: int, b2: int, *, flat: bool, kind: str = "sphere"):
     """Analytic HBM traffic in parameter passes (1 pass = read+write of d
     fp32 words). Returns (passes_per_direction, update_passes_total)."""
     if flat:
-        # zo_walk: read x + write x, directions live in VMEM only
-        per_direction = 1.0
-        # zo_replay: read x + write x once for all b2 directions
-        update_total = 1.0
+        # zo_walk: read + write x and the accumulator u; directions live in
+        # VMEM only
+        per_direction = 2.0
+        # the closing call (read + write u), then x + u
+        update_total = 2.5
     else:
         # materialize direction: gen write (0.5) [+ norm read 0.5 + scale
         # read/write 1.0 for sphere] then axpy: read x + read v + write (1.5)

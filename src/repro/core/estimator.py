@@ -35,7 +35,7 @@ Two direction *conventions* coexist (DESIGN.md §7):
 - ``conv="counter"`` the flat counter convention (round_key, n, flat
                      index) of kernels/zo_axpy.py, shared bit-for-bit with
                      the in-kernel generators of the flat-buffer hot path
-                     (``flat_coefficients`` / ``flat_apply_coefficients``
+                     (``flat_update`` / ``flat_apply_coefficients``
                      below). With this conv the pytree path and the fused
                      flat path walk the *same* directions, so their loss
                      trajectories agree to fp32 round-off — the
@@ -47,7 +47,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops as kops
-from repro.kernels.zo_axpy import counter_direction_flat
+from repro.kernels.zo_axpy import LANES, counter_direction_flat
 from repro.obs.trace import scope
 from repro.utils.flatparams import FlatSpec, flat_spec, unflatten
 from repro.utils.tree import (normal_like_tree, sphere_like_tree,
@@ -193,9 +193,9 @@ def apply_coefficients(params, rng, coeffs, *, scale=1.0, kind="sphere",
 
 
 # ---------------------------------------------------------------------------
-# flat-buffer hot path (DESIGN.md §7): fused perturbation walk + single-pass
-# seed-replay update over a FlatParams buffer, all directions regenerated
-# in-kernel from the counter convention.
+# flat-buffer hot path (DESIGN.md §7): the fused perturbation walk, which
+# carries the update too, and the single-pass seed replay over a FlatParams
+# buffer, all directions regenerated in-kernel from the counter convention.
 
 
 def flat_inv_norms(key2, spec: FlatSpec, b2, kind, *, interpret=None,
@@ -212,77 +212,96 @@ def flat_inv_norms(key2, spec: FlatSpec, b2, kind, *, interpret=None,
     return 1.0 / (jnp.sqrt(sq) + 1e-30)
 
 
-def flat_coefficients(loss_fn, buf, spec: FlatSpec, batch, rng, *, mu, b2,
-                      kind="sphere", base_loss=None, central=False,
-                      interpret=None, block_rows=None, inv=None):
-    """Fused MeZO-style perturbation walk over the flat buffer (fp32 [b2]).
+def flat_update(loss_fn, buf, spec: FlatSpec, batch, rng, *, mu, b2, scale,
+                kind="sphere", central=False, interpret=None, block_rows=None,
+                combine=None):
+    """One ZO update of the flat buffer: the fused MeZO-style perturbation
+    walk with the seed replay folded into it. Returns
+    ``(buf + scale · Σ_n c̄_n·v_n / b2, coeffs [b2, ...], base)``.
 
     Instead of perturb-then-restore (two passes between forwards), each
     step transitions x+μv_{n-1} → x+μv_n directly with one zo_walk call
     (a=−μ, b=+μ): ONE read + ONE write of the parameter buffer per
     direction, zero direction HBM traffic. Numerically this is the pytree
     path with conv="counter" up to fp32 reassociation.
+
+    The update rides the walk: step n adds eff_{n−1}·v_{n−1}, where
+    eff_n = (scale/b2)·c̄_n·inv_n, into an fp32 accumulator from the
+    direction it generates anyway (query n−1 has given c_{n−1} by then),
+    and one closing call adds the last direction's term. That is
+    ``flat_apply_coefficients``' n-ascending replay, bit for bit, without
+    its b2 further generations per iterate. ``c̄_n = combine(c_n)``: c_n
+    itself by default; the pod step passes the mean over its per-pod
+    coefficients (``loss_fn`` may return a vector, and the coefficients
+    get a matching trailing shape).
     """
     ck = COUNTER_KINDS.get(kind)
     if ck is None:
         raise ValueError(f"flat path does not support kind={kind!r}")
     key2 = kops.key_words(rng)
-    scale = _scale_factor(spec.d, kind)
-    base = (query(loss_fn, buf, spec, batch)
-            if base_loss is None else base_loss)
-    if inv is None:
-        inv = flat_inv_norms(key2, spec, b2, kind, interpret=interpret,
-                             block_rows=block_rows)
+    dscale = _scale_factor(spec.d, kind)
+    base = query(loss_fn, buf, spec, batch)
+    inv = flat_inv_norms(key2, spec, b2, kind, interpret=interpret,
+                         block_rows=block_rows)
     mu = jnp.float32(mu)
+    step = jnp.float32(scale) / b2
+    combine = combine or (lambda c: c)
+    kw = dict(kind=ck, interpret=interpret, block_rows=block_rows)
+
+    def eff(coeffs, n):
+        return step * combine(coeffs[n]) * inv[n]
 
     def body(n, carry):
-        xp, coeffs = carry
+        xp, u, coeffs = carry
         prev = jnp.maximum(n - 1, 0)
         # state entering step n: x (n=0); x+μv_{n-1} (one-sided, n>0);
-        # x−μv_{n-1} (central, n>0) — remove it and add +μv_n in one pass
+        # x−μv_{n-1} (central, n>0) — remove it and add +μv_n in one pass,
+        # and add v_{n-1}'s update term (none yet at n=0)
         a = jnp.where(n == 0, 0.0, (mu if central else -mu) * inv[prev])
         b = mu * inv[n]
-        xp = kops.zo_walk(xp, key2, jnp.stack([prev, n]), jnp.stack([a, b]),
-                          kind=ck, interpret=interpret,
-                          block_rows=block_rows)
+        e = jnp.where(n == 0, 0.0, eff(coeffs, prev))
+        xp, u = kops.zo_walk(xp, key2, jnp.stack([prev, n]),
+                             jnp.stack([a, b]), acc=(u, e), **kw)
         lp = query(loss_fn, xp, spec, batch)
         if central:
             xp = kops.zo_walk(xp, key2, jnp.stack([n, n]),
                               jnp.stack([-2 * mu * inv[n], jnp.float32(0.0)]),
-                              kind=ck, interpret=interpret,
-                              block_rows=block_rows)
+                              **kw)
             lm = query(loss_fn, xp, spec, batch)
-            c = scale * (lp - lm).astype(jnp.float32) / (2 * mu)
+            c = dscale * (lp - lm).astype(jnp.float32) / (2 * mu)
         else:
-            c = scale * (lp - base).astype(jnp.float32) / mu
-        return xp, coeffs.at[n].set(c)
+            c = dscale * (lp - base).astype(jnp.float32) / mu
+        return xp, u, coeffs.at[n].set(c)
 
-    # loss_fn may return a scalar or a vector (e.g. per-pod grouped losses);
-    # coefficients get a matching trailing shape
-    _, coeffs = jax.lax.fori_loop(
-        0, b2, body, (buf, jnp.zeros((b2,) + jnp.shape(base), jnp.float32)))
-    return coeffs, base
+    # u is carried in the kernel's [n_pad / 128, 128] layout (n_pad is a
+    # block multiple), read back as [n_pad] once the walk has ended
+    u = jnp.zeros((spec.n_pad // LANES, LANES), jnp.float32)
+    _, u, coeffs = jax.lax.fori_loop(
+        0, b2, body,
+        (buf, u, jnp.zeros((b2,) + jnp.shape(base), jnp.float32)))
+    u = kops.zo_walk(None, key2, jnp.stack([b2 - 1]), None,
+                     acc=(u, eff(coeffs, b2 - 1)), **kw)
+    new = buf.astype(jnp.float32) + u.reshape(-1)
+    return new.astype(buf.dtype), coeffs, base
 
 
 def flat_apply_coefficients(buf, spec: FlatSpec, rng, coeffs, *, scale=1.0,
-                            kind="sphere", interpret=None, block_rows=None,
-                            inv=None):
+                            kind="sphere", interpret=None, block_rows=None):
     """buf + scale · Σ_n coeffs[n]·v_n / b2 in a SINGLE pass (zo_replay).
 
-    The b2 directions are regenerated and accumulated in VMEM per block —
-    one HBM read + write of the parameter buffer total, versus b2
-    sequential axpy passes on the pytree path. Pass ``inv`` when the
-    per-direction norms were already computed (one zo_dirnorms run covers
-    both the perturb and the replay end of an iterate).
+    The seed replay of a receiver that holds only (key, coeffs) — the
+    server side of core/seedcomm.py; a client's own update rides its walk
+    (``flat_update``). The b2 directions are regenerated and accumulated
+    in VMEM per block — one HBM read + write of the parameter buffer
+    total, versus b2 sequential axpy passes on the pytree path.
     """
     ck = COUNTER_KINDS.get(kind)
     if ck is None:
         raise ValueError(f"flat path does not support kind={kind!r}")
     b2 = coeffs.shape[0]
     key2 = kops.key_words(rng)
-    if inv is None:
-        inv = flat_inv_norms(key2, spec, b2, kind, interpret=interpret,
-                             block_rows=block_rows)
+    inv = flat_inv_norms(key2, spec, b2, kind, interpret=interpret,
+                         block_rows=block_rows)
     eff = (jnp.float32(scale) / b2) * coeffs.astype(jnp.float32) * inv
     return kops.zo_replay(buf, key2, eff, kind=ck, interpret=interpret,
                           block_rows=block_rows)

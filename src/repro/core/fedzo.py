@@ -20,7 +20,7 @@ With ``cfg.flat_params=True`` the local phase runs on the flat-buffer hot
 path (DESIGN.md §7): the pytree is flattened ONCE per phase into a padded
 1-D buffer, every perturb is a fused zo_walk transition (one HBM pass per
 direction, directions regenerated in-kernel), and the b2-direction update
-is a single zo_replay pass. The pytree path stays as the reference.
+rides the same walk steps. The pytree path stays as the reference.
 
 With ``cfg.batch_directions=True`` the local phase runs the batched-
 direction ("wide") plan of the simulation engine (DESIGN.md §9): per
@@ -37,7 +37,6 @@ import jax.numpy as jnp
 
 from repro.configs.base import FedZOConfig
 from repro.core import aircomp, estimator
-from repro.kernels import ops as kops
 from repro.kernels.zo_axpy import LANES
 from repro.obs.trace import scope
 from repro.utils.flatparams import (flat_geometry, flat_spec, flatten,
@@ -89,36 +88,40 @@ def local_plan(params, cfg: FedZOConfig):
     return spec, br, phase
 
 
+def directions_per_iterate(cfg: FedZOConfig) -> int:
+    """Direction vectors one client generates per local iterate of a flat
+    plan. The wide plan draws its block once (fewer fresh ones under the
+    surrogate estimator). The flat plan generates each direction once in
+    ``zo_dirnorms`` (sphere only), twice per walk step (v_{n-1} and v_n;
+    central mode walks twice a step), and once more in the closing call
+    that adds the last direction's update term."""
+    if cfg.batch_directions:
+        return (surrogate_queries(cfg) if cfg.direction_conv == "surrogate"
+                else cfg.b2)
+    norms = cfg.b2 if cfg.estimator == "sphere" else 0
+    return norms + (4 if cfg.central else 2) * cfg.b2 + 1
+
+
 def flat_layout(params, cfg: FedZOConfig):
     """The flat buffer a run's local phase uses, for its manifest:
-    ``{"d", "n_pad", "block_rows"}`` (block_rows None where no kernel
-    runs), or None on the pytree path. n_pad / d is the pad ratio."""
+    ``{"d", "n_pad", "block_rows", "directions_per_iterate"}`` (block_rows
+    None where no kernel runs), or None on the pytree path. n_pad / d is
+    the pad ratio."""
     plan = local_plan(params, cfg)
     if plan is None:
         return None
     spec, br, _ = plan
-    return {"d": spec.d, "n_pad": spec.n_pad, "block_rows": br}
+    return {"d": spec.d, "n_pad": spec.n_pad, "block_rows": br,
+            "directions_per_iterate": directions_per_iterate(cfg)}
 
 
 def flat_local_iterate(loss_fn, buf, spec, batch, rng, cfg: FedZOConfig,
                        block_rows=None):
-    """One ZO update on the flat buffer: fused walk + single-pass replay.
-
-    The sphere inv-norms are computed once and shared by both ends — the
-    zo_dirnorms kernel regenerates all b2 directions, so running it twice
-    would double the direction-generation compute of the iterate.
-    """
-    key2 = kops.key_words(rng)
-    inv = estimator.flat_inv_norms(key2, spec, cfg.b2, cfg.estimator,
-                                   block_rows=block_rows)
-    coeffs, base = estimator.flat_coefficients(
-        loss_fn, buf, spec, batch, rng, mu=cfg.mu, b2=cfg.b2,
-        kind=cfg.estimator, central=cfg.central, block_rows=block_rows,
-        inv=inv)
-    buf = estimator.flat_apply_coefficients(
-        buf, spec, rng, coeffs, scale=-cfg.lr, kind=cfg.estimator,
-        block_rows=block_rows, inv=inv)
-    return buf, coeffs, base
+    """One ZO update on the flat buffer: the fused walk, which carries the
+    seed-replay update along (``estimator.flat_update``)."""
+    return estimator.flat_update(
+        loss_fn, buf, spec, batch, rng, mu=cfg.mu, b2=cfg.b2, scale=-cfg.lr,
+        kind=cfg.estimator, central=cfg.central, block_rows=block_rows)
 
 
 def local_iterate(loss_fn, params, batch, rng, cfg: FedZOConfig):
@@ -407,24 +410,13 @@ def make_pod_round_step(loss_fn_grouped, cfg: FedZOConfig, mesh) -> Callable:
         def flat_step(params, batch, rng):
             spec, br = flat_geometry(params, cfg.flat_block_rows)
             buf = flatten(params, spec)
-            # sphere inv-norms computed ONCE and shared by both ends — the
-            # same invariant flat_local_iterate documents (zo_dirnorms
-            # regenerates all b2 directions, so running it twice doubles
-            # the direction-generation compute of the step)
-            inv = estimator.flat_inv_norms(
-                kops.key_words(rng), spec, cfg.b2, cfg.estimator,
-                block_rows=br)
-            # flat_coefficients handles vector-valued (grouped) losses:
-            # coeffs come back [b2, n_pod]
-            coeffs, base = estimator.flat_coefficients(
-                loss_fn_grouped, buf, spec, batch, rng,
-                mu=cfg.mu, b2=cfg.b2, kind=cfg.estimator,
-                central=cfg.central, block_rows=br, inv=inv)
-            # the only cross-pod uplink: mean of per-pod coefficients
-            c_mean = jnp.mean(coeffs, axis=1)               # [b2]
-            buf = estimator.flat_apply_coefficients(
-                buf, spec, rng, c_mean, scale=-cfg.lr, kind=cfg.estimator,
-                block_rows=br, inv=inv)
+            # flat_update handles vector-valued (grouped) losses: coeffs
+            # come back [b2, n_pod]; the update takes each direction's mean
+            # over the pods, the only cross-pod uplink
+            buf, coeffs, base = estimator.flat_update(
+                loss_fn_grouped, buf, spec, batch, rng, mu=cfg.mu, b2=cfg.b2,
+                scale=-cfg.lr, kind=cfg.estimator, central=cfg.central,
+                block_rows=br, combine=jnp.mean)
             return unflatten(buf, spec), {
                 "loss": jnp.mean(base), "per_pod_loss": base,
                 "coeff_pod_spread": jnp.std(coeffs, axis=1).mean()}

@@ -70,21 +70,33 @@ def _as2d(x, block_rows):
     return xp.reshape(-1, _za.LANES), n
 
 
-def zo_walk(x, key2, nn, ab, *, kind="normal", interpret=None,
+def zo_walk(x, key2, nn, ab, *, acc=None, kind="normal", interpret=None,
             block_rows=None):
     """Fused perturbation transition on a flat [N] buffer.
 
     out = x + ab[0]·v(nn[0]) + ab[1]·v(nn[1]) with directions regenerated
     in-kernel from the counter convention (key2, n, index). One HBM pass.
+
+    ``acc=(u2, e)`` also returns u2 + e·v(nn[0]), as ``(out, u2')``;
+    ``x=None`` returns u2' alone (see ``zo_axpy.zo_walk``). The fp32
+    accumulator u2 stays in the kernel's [R, 128] layout (x's padded to
+    the block) from call to call: under vmap, a reshape between [N] and
+    [R, 128] is a relayout copy on the TPU.
     """
     block_rows = block_rows or _za.BLOCK_ROWS
-    x2, n = _as2d(x, block_rows)
-    nn = jnp.asarray(nn, jnp.int32).reshape(2)
-    ab = jnp.asarray(ab, jnp.float32).reshape(2)
-    out = _za.zo_walk(x2, key2, nn, ab, kind=kind,
-                      interpret=_auto_interpret(interpret),
+    x2, n = (None, None) if x is None else _as2d(x, block_rows)
+    if x is not None:
+        ab = jnp.asarray(ab, jnp.float32).reshape(2)
+    if acc is not None:
+        acc = (acc[0], jnp.asarray(acc[1], jnp.float32).reshape(1))
+    out = _za.zo_walk(x2, key2, jnp.asarray(nn, jnp.int32).reshape(-1), ab,
+                      acc, kind=kind, interpret=_auto_interpret(interpret),
                       block_rows=block_rows)
-    return out.reshape(-1)[:n]
+    if x is None:
+        return out
+    if acc is None:
+        return out.reshape(-1)[:n]
+    return out[0].reshape(-1)[:n], out[1]
 
 
 def zo_replay(x, key2, coeffs, *, kind="normal", interpret=None,

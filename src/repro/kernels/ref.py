@@ -39,15 +39,23 @@ def attention_ref(q, k, v, *, causal=True, window=0, scale=None):
     return out.astype(q.dtype)
 
 
-def zo_walk_ref(x2, key2, nn, ab, *, kind="normal"):
-    """Oracle for zo_walk: elementwise, so whole-array = per-block bitwise."""
+def zo_walk_ref(x2, key2, nn, ab, acc=None, *, kind="normal"):
+    """Oracle for zo_walk: elementwise, so whole-array = per-block bitwise.
+    ``acc=(u2, e)`` adds e·v(nn[0]) into u2 and returns ``(x', u')``;
+    ``x2=None``, u' alone."""
     from repro.kernels.zo_axpy import counter_gen
-    r, lanes = x2.shape
+    r, lanes = (x2 if x2 is not None else acc[0]).shape
     idx = jnp.arange(r * lanes, dtype=jnp.uint32).reshape(r, lanes)
     gp = counter_gen(kind, key2[0], key2[1], nn[0].astype(jnp.uint32), idx)
-    gn = counter_gen(kind, key2[0], key2[1], nn[1].astype(jnp.uint32), idx)
-    out = x2.astype(jnp.float32) + ab[0] * gp + ab[1] * gn
-    return out.astype(x2.dtype)
+    out = []
+    if x2 is not None:
+        gn = counter_gen(kind, key2[0], key2[1], nn[1].astype(jnp.uint32),
+                         idx)
+        out.append((x2.astype(jnp.float32) + ab[0] * gp
+                    + ab[1] * gn).astype(x2.dtype))
+    if acc is not None:
+        out.append(acc[0] + acc[1] * gp)
+    return out[0] if len(out) == 1 else tuple(out)
 
 
 def zo_replay_ref(x2, key2, coeffs, *, kind="normal"):

@@ -7,7 +7,8 @@ times per estimator sample:
   unperturb +    x ← x + a·v_n + b·v_{n+1}   (MeZO-style fused transition to
    next perturb                               the next direction: ONE pass
                                               over HBM instead of two)
-  update         x ← x − η·Σ_n c_n v_n       (replayed from seeds)
+  update         x ← x − η·Σ_n c_n v_n       (accumulated along the walk,
+                                              or replayed from seeds)
 
 These are pure HBM-bandwidth ops; the kernel's job is fusion (XLA will not
 fuse across the loss-forward boundary) and explicit VMEM tiling. The largest
@@ -39,10 +40,13 @@ directions from the counter convention.
                   transition x+μv_n → x+μv_{n+1} (a=−μ, b=+μ). This is
                   ``zo_axpy2`` with u, v generated in VMEM instead of
                   streamed from HBM: ONE read + ONE write of x per
-                  direction, zero direction traffic.
+                  direction, zero direction traffic. With an accumulator
+                  it also adds e·v(n_prev) into the update's u in place,
+                  so the update needs no pass of its own.
 - ``zo_replay``   x + Σ_n c_n·v_n accumulated in VMEM per block, one HBM
                   writeback per block — the whole b2-direction update in a
-                  single pass over the parameter buffer.
+                  single pass over the parameter buffer, for a receiver
+                  that holds only the seeds and coefficients.
 - ``zo_dirnorms`` per-direction squared norms ‖g_n‖² (for the sphere
                   estimator's normalization) with ~zero HBM traffic: the
                   directions live only in VMEM, the output is [b2] floats.
@@ -213,40 +217,64 @@ def _block_idx(i, rows, lanes):
 # -- zo_walk: fused perturbation transition --------------------------------
 
 
-def _walk_kernel(key_ref, nn_ref, ab_ref, x_ref, o_ref, *, kind):
-    i = pl.program_id(0)
-    rows, lanes = x_ref.shape
-    idx = _block_idx(i, rows, lanes)
+def _walk_kernel(key_ref, nn_ref, coef_ref, *refs, kind, walk, acc):
+    # refs: the streamed inputs (x if walk, u if acc), then their outputs
+    ins, outs = refs[:walk + acc], refs[walk + acc:]
+    rows, lanes = outs[0].shape
+    idx = _block_idx(pl.program_id(0), rows, lanes)
     k0, k1 = key_ref[0, 0], key_ref[1, 0]
     g_prev = counter_gen(kind, k0, k1, nn_ref[0, 0].astype(jnp.uint32), idx)
-    g_next = counter_gen(kind, k0, k1, nn_ref[1, 0].astype(jnp.uint32), idx)
-    x = x_ref[...].astype(jnp.float32)
-    o_ref[...] = (x + ab_ref[0, 0] * g_prev
-                  + ab_ref[1, 0] * g_next).astype(o_ref.dtype)
+    if walk:
+        g_next = counter_gen(kind, k0, k1, nn_ref[1, 0].astype(jnp.uint32),
+                             idx)
+        x = ins[0][...].astype(jnp.float32)
+        outs[0][...] = (x + coef_ref[0, 0] * g_prev
+                        + coef_ref[1, 0] * g_next).astype(outs[0].dtype)
+    if acc:
+        e = coef_ref[2 if walk else 0, 0]
+        outs[-1][...] = ins[-1][...] + e * g_prev
 
 
 @functools.partial(jax.jit, static_argnames=("kind", "interpret", "block_rows"))
-def zo_walk(x2, key2, nn, ab, *, kind="normal", interpret=False,
+def zo_walk(x2, key2, nn, ab, acc=None, *, kind="normal", interpret=False,
             block_rows=BLOCK_ROWS):
     """x + ab[0]·v(nn[0]) + ab[1]·v(nn[1]) with in-kernel direction regen.
 
     x2: [R, 128] (R divisible by block_rows); key2 uint32 [2]; nn int32 [2]
     direction indices; ab fp32 [2] coefficients (pass ab[0]=0 for the first
     perturbation of a walk). One read + one write of x: 1 HBM pass.
+
+    ``acc=(u2, e)``, u2 fp32 [R, 128] and e an fp32 scalar, also adds
+    e·v(nn[0]) into the accumulator u from the direction the step already
+    generates, and returns ``(x', u')``; both are updated in place. With
+    ``x2=None`` (``ab`` then unused) the call is the accumulation alone and
+    generates v(nn[0]) only: it closes a walk's last direction.
     """
-    r, lanes = x2.shape
-    assert lanes == LANES and r % block_rows == 0, (x2.shape, block_rows)
+    u2, e = (None, None) if acc is None else acc
+    coef = [c for c in (None if x2 is None else ab, e) if c is not None]
+    streams = [a for a in (x2, u2) if a is not None]
+    r, lanes = streams[0].shape
+    assert lanes == LANES and r % block_rows == 0, (r, lanes, block_rows)
     grid = (r // block_rows,)
     spec = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
-    return pl.pallas_call(
-        functools.partial(_walk_kernel, kind=kind),
+    shapes = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in streams]
+    one = len(streams) == 1
+    out = pl.pallas_call(
+        functools.partial(_walk_kernel, kind=kind, walk=x2 is not None,
+                          acc=acc is not None),
         grid=grid,
-        in_specs=[SMEM, SMEM, SMEM, spec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((r, LANES), x2.dtype),
+        in_specs=[SMEM, SMEM, SMEM] + [spec] * len(streams),
+        out_specs=spec if one else [spec] * len(streams),
+        out_shape=shapes[0] if one else shapes,
+        # in place only with an accumulator, whose walk loop carries both
+        # streams; the walk alone writes a fresh x'
+        input_output_aliases=({} if acc is None else
+                              {3 + j: j for j in range(len(streams))}),
         interpret=interpret,
-    )(_col(key2, jnp.uint32), _col(nn, jnp.int32), _col(ab, jnp.float32),
-      x2)
+    )(_col(key2, jnp.uint32), _col(nn, jnp.int32),
+      _col(jnp.concatenate([jnp.ravel(jnp.asarray(c, jnp.float32))
+                            for c in coef]), jnp.float32), *streams)
+    return out if one else tuple(out)
 
 
 # -- zo_replay: single-pass seed-replay update ------------------------------

@@ -448,17 +448,22 @@ def test_sweep_tracer_one_compile_per_static_group():
 
 
 @pytest.mark.parametrize("plan,want", [
-    ({"flat_params": True}, {"d": 39, "n_pad": 1024, "block_rows": 8}),
+    ({"flat_params": True},
+     {"d": 39, "n_pad": 1024, "block_rows": 8, "directions_per_iterate": 7}),
     ({"flat_params": True, "flat_block_rows": 16},
-     {"d": 39, "n_pad": 2048, "block_rows": 16}),
+     {"d": 39, "n_pad": 2048, "block_rows": 16,
+      "directions_per_iterate": 7}),
     ({"batch_directions": True, "direction_conv": "block"},
-     {"d": 39, "n_pad": 128, "block_rows": None}),
+     {"d": 39, "n_pad": 128, "block_rows": None,
+      "directions_per_iterate": 2}),
     ({}, None),
 ], ids=["flat_derived", "flat_explicit", "wide", "pytree"])
 def test_manifest_records_flat_geometry(tmp_path, plan, want):
     """The manifest says which flat buffer the kernels ran on: d, n_pad
-    and block_rows (the pad ratio is n_pad / d); no block on the wide path
-    without AirComp, which runs no kernel; nothing on the pytree path."""
+    and block_rows (the pad ratio is n_pad / d), and the direction vectors
+    a client generates per iterate (b2 = 2: 2 for the norms, 4 in the
+    walk, 1 closing; the wide block 2); no block on the wide path without
+    AirComp, which runs no kernel; nothing on the pytree path."""
     path = os.path.join(tmp_path, "rows.jsonl")
     with obs.JsonlSink(path) as sink:
         res = engine.run_experiment(softmax_loss, _params(), _setup(),

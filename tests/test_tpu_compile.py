@@ -51,12 +51,28 @@ def _compiled_text(fn, *shapes, sharding):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def test_zo_walk_compiles(one_chip):
-    text = _compiled_text(
-        lambda x, k, nn, ab: za.zo_walk(x, k, nn, ab, interpret=False),
-        ((D // 128, 128), jnp.float32), ((2,), jnp.uint32),
-        ((2,), jnp.int32), ((2,), jnp.float32), sharding=one_chip)
+@pytest.mark.parametrize("acc", [None, "walk", "close"])
+def test_zo_walk_compiles(one_chip, acc):
+    """The walk alone, the walk carrying its aliased accumulator, and the
+    closing accumulation alone."""
+    x = u = ((D // 128, 128), jnp.float32)
+    key, nn = ((2,), jnp.uint32), ((2,), jnp.int32)
+    ab, e = ((2,), jnp.float32), ((1,), jnp.float32)
+    if acc is None:
+        fn = lambda x, k, nn, ab: za.zo_walk(x, k, nn, ab, interpret=False)
+        shapes = (x, key, nn, ab)
+    elif acc == "walk":
+        fn = lambda x, k, nn, ab, u, e: za.zo_walk(x, k, nn, ab, (u, e),
+                                                   interpret=False)
+        shapes = (x, key, nn, ab, u, e)
+    else:
+        fn = lambda k, nn, u, e: za.zo_walk(None, k, nn, None, (u, e),
+                                            interpret=False)
+        shapes = (key, ((1,), jnp.int32), u, e)
+    text = _compiled_text(fn, *shapes, sharding=one_chip)
     assert "tpu_custom_call" in text
+    if acc is not None:
+        assert "output_to_operand_aliasing" in text
 
 
 def test_zo_replay_compiles(one_chip):
@@ -85,7 +101,8 @@ def test_aircomp_reduce_compiles(one_chip, m, n):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("kernel", ["zo_walk", "zo_replay", "zo_dirnorms"])
+@pytest.mark.parametrize("kernel", ["zo_walk", "zo_walk_acc", "zo_replay",
+                                    "zo_dirnorms"])
 def test_kernels_compile_vmapped_over_cohort(one_chip, kernel):
     """The flat round vmaps every kernel over the M cohort clients, which
     batches the scalar SMEM operands too (one key per client)."""
@@ -96,6 +113,10 @@ def test_kernels_compile_vmapped_over_cohort(one_chip, kernel):
         fn = jax.vmap(lambda x, k, ab: za.zo_walk(
             x, k, jnp.asarray([0, 1]), ab, interpret=False))
         shapes = (x, keys, ((m, 2), jnp.float32))
+    elif kernel == "zo_walk_acc":
+        fn = jax.vmap(lambda x, k, ab, u, e: za.zo_walk(
+            x, k, jnp.asarray([0, 1]), ab, (u, e), interpret=False))
+        shapes = (x, keys, ((m, 2), jnp.float32), x, ((m, 1), jnp.float32))
     elif kernel == "zo_replay":
         fn = jax.vmap(lambda x, k, c: za.zo_replay(x, k, c, interpret=False))
         shapes = (x, keys, ((m, B2), jnp.float32))
@@ -140,7 +161,8 @@ def test_scopes_and_kernel_names_in_compiled_program(one_chip, monkeypatch):
     program's registered scopes on the instructions' ``op_name`` paths,
     and the kernel instruction names the trace reducer keys on. The flat
     AirComp experiment with a fading channel and an in-scan eval runs
-    every registered scope and all four kernels."""
+    every registered scope and the flat path's three kernels; the
+    update rides the walk, so no zo_replay op is left."""
     import re
 
     from repro import sim
@@ -178,8 +200,10 @@ def test_scopes_and_kernel_names_in_compiled_program(one_chip, monkeypatch):
     instrs = set(re.findall(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = ", text,
                             re.MULTILINE))
     bases = {re.sub(r"\.\d+$", "", n) for n in instrs}
-    for kernel in ("zo_walk", "zo_replay", "zo_dirnorms", "aircomp_reduce"):
+    for kernel in ("zo_walk", "zo_dirnorms", "aircomp_reduce"):
         assert kernel in bases, kernel
+    # the update rides the walk: no zo_replay op on the flat local path
+    assert "zo_replay" not in bases
 
 
 def test_flat_experiment_kernels_sized_to_d(one_chip, monkeypatch):
@@ -214,8 +238,7 @@ def test_flat_experiment_kernels_sized_to_d(one_chip, monkeypatch):
     calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
     names = {re.sub(r"\.\d+$", "", re.search(r"%([\w.\-]+) = ", l)[1])
              for l in calls}
-    assert names == {"zo_walk", "zo_replay", "zo_dirnorms",
-                     "aircomp_reduce"}, names
+    assert names == {"zo_walk", "zo_dirnorms", "aircomp_reduce"}, names
     blocked = [rows for l in calls
                for rows in re.findall(r"f32\[(?:\d+,)*?(\d+),128\]", l)]
     assert blocked and set(blocked) == {"64"}, blocked
@@ -262,7 +285,7 @@ def test_fedavg_cnn_experiment_at_published_widths(one_chip, monkeypatch):
     calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
     names = {re.sub(r"\.\d+$", "", re.search(r"%([\w.\-]+) = ", l)[1])
              for l in calls}
-    assert names == {"zo_walk", "zo_replay", "zo_dirnorms"}, names
+    assert names == {"zo_walk", "zo_dirnorms"}, names
     buffers = {rows for l in calls
                for rows in re.findall(r"f32\[10,(\d+),128\]", l)}
     assert buffers == {str(26 * 504)}, buffers

@@ -46,15 +46,26 @@ def test_zo_replay_bit_equals_reference(kind, nblocks):
 
 
 @pytest.mark.parametrize("kind", ["normal", "sign"])
-def test_zo_walk_bit_equals_reference(kind):
-    n = 2 * BR * 128
+@pytest.mark.parametrize("nblocks", [1, 3])
+@pytest.mark.parametrize("acc", [None, "walk", "close"])
+def test_zo_walk_bit_equals_reference(kind, nblocks, acc):
+    """The walk alone, the walk carrying an accumulator u + e·v(nn[0]),
+    and the accumulation alone (x=None, the closing call)."""
+    n = nblocks * BR * 128
     x = jax.random.normal(jax.random.key(0), (n,), jnp.float32)
+    # the accumulator in the kernel's [R, 128] layout
+    u = jax.random.normal(jax.random.key(1), (n // 128, 128), jnp.float32)
     nn = jnp.asarray([3, 4], jnp.int32)
     ab = jnp.asarray([-0.25, 0.125], jnp.float32)
-    out = ops.zo_walk(x, KEY2, nn, ab, kind=kind, block_rows=BR)
+    e = jnp.float32(-0.375)
+    xa = None if acc == "close" else x
+    pair = None if acc is None else (u, e)
+    out = ops.zo_walk(xa, KEY2, nn, ab, acc=pair, kind=kind, block_rows=BR)
     r = jax.jit(functools.partial(ref.zo_walk_ref, kind=kind))(
-        x.reshape(-1, 128), KEY2, nn, ab).reshape(-1)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(r))
+        None if xa is None else xa.reshape(-1, 128), KEY2, nn, ab, pair)
+    for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(r)):
+        np.testing.assert_array_equal(np.asarray(a).reshape(-1),
+                                      np.asarray(b).reshape(-1))
 
 
 def test_zo_dirnorms_matches_reference_and_direct():
@@ -174,10 +185,9 @@ def test_flat_trajectory_matches_pytree_over_20_iterates():
 
 
 def test_pod_step_computes_dirnorms_once(monkeypatch):
-    """Regression: the pod-step flat path used to call flat_coefficients
-    and flat_apply_coefficients without a shared ``inv``, running the
-    zo_dirnorms kernel twice per step (the invariant flat_local_iterate
-    documents). The step must compute the inv-norms exactly once."""
+    """Regression: the pod-step flat path once computed the inv-norms for
+    the walk and again for the update, running the zo_dirnorms kernel
+    twice per step. The step must compute the inv-norms exactly once."""
     calls = []
     orig = estimator.flat_inv_norms
 
@@ -322,3 +332,200 @@ def test_seedcomm_round_trip_under_derived_geometry():
                                                          cfg), params, cfg)
     for a, b in zip(jax.tree.leaves(delta), jax.tree.leaves(recon)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-7)
+
+
+# -- 5. the seed-replay update rides the perturbation walk ------------------
+
+
+def _walk_then_replay(loss_fn, buf, spec, batch, rng, *, mu, b2, lr, kind,
+                      central, block_rows, combine=None):
+    """The reference composition the fused walk replaced: the perturbation
+    walk alone, then one zo_replay pass of the (combined) coefficients
+    (``flat_apply_coefficients``)."""
+    ck = estimator.COUNTER_KINDS[kind]
+    key2 = ops.key_words(rng)
+    scale = estimator._scale_factor(spec.d, kind)
+    base = estimator.query(loss_fn, buf, spec, batch)
+    inv = estimator.flat_inv_norms(key2, spec, b2, kind,
+                                   block_rows=block_rows)
+    mu = jnp.float32(mu)
+
+    def body(n, carry):
+        xp, coeffs = carry
+        prev = jnp.maximum(n - 1, 0)
+        a = jnp.where(n == 0, 0.0, (mu if central else -mu) * inv[prev])
+        xp = ops.zo_walk(xp, key2, jnp.stack([prev, n]),
+                         jnp.stack([a, mu * inv[n]]), kind=ck,
+                         block_rows=block_rows)
+        lp = estimator.query(loss_fn, xp, spec, batch)
+        if central:
+            xp = ops.zo_walk(xp, key2, jnp.stack([n, n]),
+                             jnp.stack([-2 * mu * inv[n], jnp.float32(0.0)]),
+                             kind=ck, block_rows=block_rows)
+            lm = estimator.query(loss_fn, xp, spec, batch)
+            c = scale * (lp - lm).astype(jnp.float32) / (2 * mu)
+        else:
+            c = scale * (lp - base).astype(jnp.float32) / mu
+        return xp, coeffs.at[n].set(c)
+
+    _, coeffs = jax.lax.fori_loop(
+        0, b2, body, (buf, jnp.zeros((b2,) + jnp.shape(base), jnp.float32)))
+    c = coeffs if combine is None else combine(coeffs)
+    new = estimator.flat_apply_coefficients(buf, spec, rng, c, scale=-lr,
+                                            kind=kind, block_rows=block_rows)
+    return new, coeffs, base
+
+
+def _quadratic_loss(p, b):
+    return 0.5 * jnp.mean((p["w"] - b["target"]) ** 2)
+
+
+def _iterate_case(model):
+    """(loss, params, batch): the softmax model at the paper's d, whose
+    derived geometry is one 64-row block, or a d = 150,000 vector whose
+    derived geometry is three grid steps."""
+    if model == "softmax":
+        x, y = make_classification(64, 784, 10, seed=0)
+        return (softmax_loss, softmax_init(None),
+                {"x": jnp.asarray(x[:16]), "y": jnp.asarray(y[:16])})
+    d = 150_000
+    return (_quadratic_loss,
+            {"w": jax.random.normal(jax.random.key(2), (d,))},
+            {"target": jax.random.normal(jax.random.key(3), (d,))})
+
+
+@pytest.mark.parametrize("model", ["softmax", "three_steps"])
+@pytest.mark.parametrize("kind", ["sphere", "gaussian"])
+@pytest.mark.parametrize("central", [False, True],
+                         ids=["one_sided", "central"])
+def test_fused_iterate_bit_equals_walk_then_replay(model, kind, central):
+    """The iterate whose walk carries the update lands on the walk +
+    zo_replay composition bit for bit: new buffer, coefficients, loss."""
+    loss, params, batch = _iterate_case(model)
+    cfg = FedZOConfig(b2=5, lr=1e-2, mu=1e-3, flat_params=True,
+                      estimator=kind, central=central)
+    spec, br, _ = fedzo.local_plan(params, cfg)
+    assert spec.n_pad // (br * 128) == (1 if model == "softmax" else 3)
+    buf, rng = flatten(params, spec), jax.random.key(7)
+    got = jax.jit(lambda b, k: fedzo.flat_local_iterate(
+        loss, b, spec, batch, k, cfg, block_rows=br))(buf, rng)
+    want = jax.jit(lambda b, k: _walk_then_replay(
+        loss, b, spec, batch, k, mu=cfg.mu, b2=cfg.b2, lr=cfg.lr, kind=kind,
+        central=central, block_rows=br))(buf, rng)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+class _FakeMesh:
+    shape = {"pod": 2}
+
+
+@pytest.mark.parametrize("central", [False, True],
+                         ids=["one_sided", "central"])
+def test_pod_step_bit_equals_walk_then_replay(central):
+    """The pod step's update is the mean over pods of each direction's
+    coefficient, known after its query: riding the walk, it gives the
+    walk + replay composition's parameters bit for bit."""
+    cfg = FedZOConfig(b2=4, lr=0.05, mu=1e-3, flat_params=True,
+                      flat_block_rows=BR, central=central)
+    params = {"x": jax.random.normal(jax.random.key(4), (900,))}
+    batch = {"target": jnp.ones((900,))}
+
+    def loss_grouped(p, b):
+        l = 0.5 * jnp.sum((p["x"] - b["target"]) ** 2)
+        return jnp.stack([l, l * 1.01])
+
+    step = fedzo.make_pod_round_step(loss_grouped, cfg, _FakeMesh())
+    rng = jax.random.key(5)
+    newp, metrics = jax.jit(step)(params, batch, rng)
+    spec = flat_spec(params, block=BR * 128)
+
+    def ref_step(p, k):
+        return _walk_then_replay(
+            loss_grouped, flatten(p, spec), spec, batch, k, mu=cfg.mu,
+            b2=cfg.b2, lr=cfg.lr, kind=cfg.estimator, central=central,
+            block_rows=BR, combine=lambda c: jnp.mean(c, axis=1))
+
+    want, _, base = jax.jit(ref_step)(params, rng)
+    np.testing.assert_array_equal(np.asarray(newp["x"]),
+                                  np.asarray(unflatten(want, spec)["x"]))
+    np.testing.assert_array_equal(np.asarray(metrics["per_pod_loss"]),
+                                  np.asarray(base))
+
+
+ZO_KERNELS = ("zo_walk", "zo_replay", "zo_dirnorms")
+
+
+def _generated(eqn, name):
+    """Direction vectors one call of a ZO kernel generates: a walk step
+    both v(nn[0]) and v(nn[1]), the closing call v(nn[0]) alone (its nn
+    holds one index), zo_dirnorms and zo_replay all b2."""
+    if name == "zo_walk":
+        nn = next(v for v in eqn.invars if v.aval.dtype == jnp.int32)
+        return nn.aval.shape[0]
+    if name == "zo_dirnorms":
+        return eqn.outvars[0].aval.shape[0]
+    return eqn.invars[2].aval.shape[0]
+
+
+def _kernel_calls(jaxpr, mult=1, out=None):
+    """Calls of each ZO kernel a jaxpr makes when it runs, loops multiplied
+    out by their trip counts, and the direction vectors they generate."""
+    import collections
+    out = collections.Counter() if out is None else out
+    for eqn in jaxpr.eqns:
+        prim, name = eqn.primitive.name, eqn.params.get("name")
+        if prim in ("jit", "pjit") and name in ZO_KERNELS:
+            out[name] += mult
+            out["directions"] += mult * _generated(eqn, name)
+            continue
+        assert prim not in ("while", "cond"), prim
+        times = mult * (eqn.params["length"] if prim == "scan" else 1)
+        for v in eqn.params.values():
+            sub = getattr(v, "jaxpr", v)
+            if hasattr(sub, "eqns"):
+                _kernel_calls(sub, times, out)
+    return out
+
+
+@pytest.mark.parametrize("central", [False, True],
+                         ids=["one_sided", "central"])
+def test_flat_local_phase_walks_and_never_replays(central):
+    """The flat local phase makes H·(b2+1) zo_walk calls (H·(2·b2+1) in
+    central mode), the closing call of each iterate included, and no
+    zo_replay call; the directions its kernels generate are the manifest's
+    ``directions_per_iterate`` per iterate."""
+    h, b2 = 3, 4
+    cfg = FedZOConfig(local_iters=h, b2=b2, lr=0.05, mu=1e-3,
+                      flat_params=True, flat_block_rows=BR, central=central)
+    params = {"x": jnp.zeros((40,))}
+    batches = {"target": jnp.ones((h, 40))}
+
+    def loss(p, b):
+        return 0.5 * jnp.sum((p["x"] - b["target"]) ** 2)
+
+    jaxpr = jax.make_jaxpr(lambda p, b, k: fedzo.local_phase(
+        loss, p, b, k, cfg))(params, batches, jax.random.key(0))
+    calls = _kernel_calls(jaxpr.jaxpr)
+    assert calls["zo_walk"] == h * ((2 if central else 1) * b2 + 1)
+    assert calls["zo_replay"] == 0
+    assert calls["zo_dirnorms"] == h
+    assert calls["directions"] == h * fedzo.directions_per_iterate(cfg)
+    layout = fedzo.flat_layout(params, cfg)
+    assert layout["directions_per_iterate"] == \
+        fedzo.directions_per_iterate(cfg)
+
+
+@pytest.mark.parametrize("kw,want", [
+    ({}, 61),
+    ({"estimator": "gaussian"}, 41),
+    ({"central": True}, 101),
+    ({"batch_directions": True, "direction_conv": "block"}, 20),
+], ids=["sphere", "gaussian", "central", "wide"])
+def test_flat_layout_reports_directions_per_iterate(kw, want):
+    """At the paper's b2 = 20 a sphere client generates 20 directions for
+    the norms, 40 in the walk and 1 in the closing call: 61, where the
+    walk followed by a zo_replay pass generated 80."""
+    cfg = FedZOConfig(b2=20, flat_params=True, **kw)
+    layout = fedzo.flat_layout(softmax_init(None), cfg)
+    assert layout["directions_per_iterate"] == want
